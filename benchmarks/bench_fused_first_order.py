@@ -6,10 +6,9 @@ one-kernel-per-extension path pays ~3× (three passes over the same
 (grad_out, input) pair).  Lanes per Dense benchmark shape (N, R, a, b):
 
   fused/l2_only     fused kernel, mask = {l2}            (the 1× baseline)
-  fused/all3        fused kernel, mask = {l2, moment, dot}
+  fused/all3        fused kernel, mask = {l2, moment}, + cross_dot for dot
   per_ext/all3      the seed's per-extension path: batch_l2 kernel +
                     per_sample_moment kernel + jnp Gram-einsum batch_dot
-                    (no standalone dot kernel ever existed)
   jnp/all3          pure-jnp einsum oracles
 
 ``derived`` carries the ratio vs fused/l2_only.  Numbers here are
@@ -37,8 +36,10 @@ QUICK_SHAPES = [(8, 32, 128, 128)]
 
 
 def _fused(A, B, wl, wm, wd):
-    return ops.fused_first_order(A, B, want_l2=wl, want_moment=wm,
-                                 want_dot=wd)
+    out = ops.fused_first_order(A, B, want_l2=wl, want_moment=wm)
+    if wd:
+        out["dot"] = ops.cross_dot(A, B, A, B)
+    return out
 
 
 _batch_dot_jnp = jax.jit(lambda A, B: ref.batch_dot(A, B))

@@ -45,6 +45,7 @@ import json
 import os
 
 from benchmarks import common
+from repro.launch.cache import enable_compile_cache
 
 
 def main() -> None:
@@ -57,6 +58,7 @@ def main() -> None:
                     help="also write all rows as JSON to this path")
     ap.add_argument("which", nargs="*", help="benchmark names (default: all)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.json_path:
         # Fail before minutes of benchmarking, not after.
         parent = os.path.dirname(os.path.abspath(args.json_path))

@@ -54,10 +54,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import obs
 
-try:  # jax >= 0.5 exposes it at top level
-    from jax import shard_map as _shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from .extensions import (
     Extension,
@@ -111,7 +108,7 @@ class SweepPlan:
         passes = 1 + sum(s in self.sweeps
                          for s in ("ggn_exact", "ggn_mc", "jac", "kfra",
                                    "hess"))
-        fused = [k for k in ("l2", "moment", "dot")
+        fused = [k for k in ("l2", "moment")
                  if getattr(self.fused_mask, k)]
         lane = fused if self.fused_active and fused else None
         # The second-order lane reports the *planned* kernel outputs for the
@@ -450,23 +447,21 @@ def _sharded_moment_triple(sum_g2, grad_local, n_local, axes):
     """Global (count, mean, M2) triple across shards, moment-merge style.
 
     Each shard contributes its local (Σg, Σg²) as a (count, mean, M2)
-    triple; a binary tree of :func:`_chan_merge` steps combines the
-    all-gathered triples without ever forming the catastrophically
+    triple, combined by the k-way form of Chan's merge,
+    ``M2 = Σ_s M2_s + Σ_s n_s (mean_s − mean)²``, in two psums: the
+    deviations are taken around the global mean, so the catastrophically
     cancelling global Σg² − (Σg)²/n difference between large
-    intermediates.  ``n·M2`` of the result equals the engine's
-    single-device ``n·Σg² − (Σg)²`` in exact arithmetic.
+    intermediates never forms, and no shard holds another shard's
+    parameter-sized moments (an all-gather of them costs 2·shards
+    parameter copies per device).  ``n·M2`` of the result equals the
+    engine's single-device ``n·Σg² − (Σg)²`` in exact arithmetic.
     """
-    g1 = jax.lax.all_gather(grad_local.astype(jnp.float32), tuple(axes))
-    g2 = jax.lax.all_gather(sum_g2, tuple(axes))
-    parts = [_moment_triple(g2[i], g1[i], n_local)
-             for i in range(g1.shape[0])]
-    while len(parts) > 1:
-        merged = [_chan_merge(parts[i], parts[i + 1])
-                  for i in range(0, len(parts) - 1, 2)]
-        if len(parts) % 2:
-            merged.append(parts[-1])
-        parts = merged
-    return parts[0]
+    axes = tuple(axes)
+    nl, mean_l, m2_l = _moment_triple(sum_g2, grad_local, n_local)
+    n = jax.lax.psum(nl, axes)
+    mean = jax.lax.psum(grad_local.astype(jnp.float32), axes) / n
+    m2 = jax.lax.psum(m2_l + nl * (mean_l - mean) ** 2, axes)
+    return n, mean, m2
 
 
 def _sharded_variance(sum_g2, grad_local, n_local, axes):
@@ -604,10 +599,11 @@ class ShardedSweepPlan:
                                          self.axes)
             return res.loss, res.grads, res.logits, ext
 
-        fn = _shard_map(body, mesh=self.mesh,
-                        in_specs=(P(), batch, batch, P()),
-                        out_specs=(P(), P(), batch, ext_specs),
-                        check_rep=False)
+        # jit: an un-jitted shard_map runs its body op by op.
+        fn = jax.jit(_shard_map(body, mesh=self.mesh,
+                                in_specs=(P(), batch, batch, P()),
+                                out_specs=(P(), P(), batch, ext_specs),
+                                check_vma=False))
         with obs.span("engine/sweep", lane="sharded", shards=self.n_shards,
                       extensions=",".join(sorted(self.plan.names))):
             loss_val, grads, logits, ext = fn(params, inputs, targets, rng)
@@ -959,10 +955,10 @@ class AccumulatedSweepPlan:
                                          sp.axes)
             return lv, grads, logits, ext
 
-        fn = _shard_map(body, mesh=sp.mesh,
-                        in_specs=(P(), batch, batch, P(), P()),
-                        out_specs=(P(), P(), batch, ext_specs),
-                        check_rep=False)
+        fn = jax.jit(_shard_map(body, mesh=sp.mesh,
+                                in_specs=(P(), batch, batch, P(), P()),
+                                out_specs=(P(), P(), batch, ext_specs),
+                                check_vma=False))
         with obs.span("engine/sweep", lane="shard_accumulate",
                       k=k, n=n, shards=sp.n_shards,
                       extensions=",".join(sorted(self.plan.names))):
@@ -1302,7 +1298,7 @@ class SweepStream:
                          batch, {nm: batch for nm in self.concat_names})
             self._jit_cache["sharded"] = jax.jit(_shard_map(
                 body, mesh=sp.mesh, in_specs=(P(), batch, batch, P(), P()),
-                out_specs=out_specs, check_rep=False))
+                out_specs=out_specs, check_vma=False))
         return self._jit_cache["sharded"]
 
     def _pair_diag(self):

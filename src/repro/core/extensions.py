@@ -184,22 +184,20 @@ class FusedMask:
     """Static extension mask for the fused first-order kernel.
 
     Maps 1:1 onto the fused kernel's outputs: ``l2`` ↔ BatchL2, ``moment`` ↔
-    SecondMoment/Variance (both reduce the summed squared gradient), ``dot``
-    ↔ BatchDot.  An unset flag means that output is never allocated or
-    computed inside the kernel.
+    SecondMoment/Variance (both reduce the summed squared gradient).  An
+    unset flag means that output is never allocated or computed inside the
+    kernel.  BatchDot's pairwise dots are the ``cross_dot`` kernel's.
     """
 
     l2: bool = False
     moment: bool = False
-    dot: bool = False
 
     def any(self) -> bool:
-        return self.l2 or self.moment or self.dot
+        return self.l2 or self.moment
 
     def wants(self):
         """Kwargs for ``kernels.ops.fused_first_order``."""
-        return dict(want_l2=self.l2, want_moment=self.moment,
-                    want_dot=self.dot)
+        return dict(want_l2=self.l2, want_moment=self.moment)
 
 
 def first_order_mask(exts_or_names) -> FusedMask:
@@ -208,7 +206,6 @@ def first_order_mask(exts_or_names) -> FusedMask:
     return FusedMask(
         l2="batch_l2" in names,
         moment=bool(names & {"second_moment", "variance"}),
-        dot="batch_dot" in names,
     )
 
 

@@ -128,35 +128,33 @@ def _pairwise_rows(ps, shard_axes=None, cross_split=None):
     ``shard_axes``) the batch is a concatenated microbatch pair and only
     the cross block ``rows[:cs] @ rows[cs:].T`` is emitted.
     """
-    f = _f32(ps).reshape(ps.shape[0], -1)
+    rows, cols = _pair_operands(_f32(ps).reshape(ps.shape[0], -1),
+                                shard_axes, cross_split)
+    return rows @ cols.T
+
+
+def _pair_operands(x, shard_axes=None, cross_split=None):
+    """(rows, cols) sample sets [.., ...] a pairwise stat pairs up: all ×
+    all on one device, the local rows × the all-gathered batch under a
+    sharded sweep (gathering factors costs activation-sized traffic, not
+    [N, a, b] per-sample gradients), the ``[:cs] × [cs:]`` cross block
+    under ``cross_split``.  The sample axis is the leading one."""
     if cross_split is not None:
-        return f[:cross_split] @ f[cross_split:].T
-    cols = (jax.lax.all_gather(f, shard_axes, axis=0, tiled=True)
-            if shard_axes else f)
-    return f @ cols.T
-
-
-def per_sample_dots(A, B, shard_axes=None, cross_split=None):
-    """D[n,m] = ⟨g_n, g_m⟩ for g = A_nᵀB_n — pairwise Gram trick.
-
-    A: [N, R, a], B: [N, R, b] → [rows, M] float32; rows == M == N
-    single-device, global N columns under a sharded sweep (row block vs
-    the all-gathered factors — gathering (A, B) costs activation-sized
-    traffic instead of the [N, a, b] per-sample gradients), and the
-    ``[cs, N - cs]`` cross block under ``cross_split`` (the streaming
-    pair passes).  diag of the assembled matrix == batch_l2.
-    """
-    A, B = _f32(A), _f32(B)
-    if cross_split is not None:
-        ga = jnp.einsum("nra,msa->nmrs", A[:cross_split], A[cross_split:])
-        gb = jnp.einsum("nrb,msb->nmrs", B[:cross_split], B[cross_split:])
-        return jnp.sum(ga * gb, axis=(2, 3))
-    Am, Bm = A, B
+        return x[:cross_split], x[cross_split:]
     if shard_axes:
-        Am = jax.lax.all_gather(A, shard_axes, axis=0, tiled=True)
-        Bm = jax.lax.all_gather(B, shard_axes, axis=0, tiled=True)
-    ga = jnp.einsum("nra,msa->nmrs", A, Am)
-    gb = jnp.einsum("nrb,msb->nmrs", B, Bm)
+        return x, jax.lax.all_gather(x, shard_axes, axis=0, tiled=True)
+    return x, x
+
+
+def per_sample_dots(A1, B1, A2, B2):
+    """D[n,m] = ⟨g_n, g'_m⟩ for g = A_nᵀB_n — pairwise Gram trick.
+
+    A1/B1: [N, R, a/b] row samples, A2/B2: [M, R, a/b] column samples
+    (:func:`_pair_operands`) → [N, M] float32.  diag of the full matrix
+    == batch_l2.
+    """
+    ga = jnp.einsum("nra,msa->nmrs", A1, A2)
+    gb = jnp.einsum("nrb,msb->nmrs", B1, B2)
     return jnp.sum(ga * gb, axis=(2, 3))
 
 
@@ -194,12 +192,13 @@ def dense_first_order_stats(A, B, exts, cfg: ExtensionConfig, bias: bool):
     A: [N, R, a] inputs, B: [N, R, b] output cotangents (already / m).
     Returns ``{ext_name: {'w': ..., 'b': ...}}``.
 
-    With ``cfg.use_kernels`` (and ``cfg.use_fused``, the default) every
-    requested weight reduction — batch_l2, summed squared gradient, pairwise
-    dots — comes out of ONE fused Pallas launch over (A, B); the static
-    :class:`~repro.core.extensions.FusedMask` selects the outputs.  With
-    ``use_fused=False`` each statistic runs its own legacy kernel (the
-    benchmark baseline).  Bias stats are cheap row-sums and stay in jnp.
+    With ``cfg.use_kernels`` (and ``cfg.use_fused``, the default) batch_l2
+    and the summed squared gradient come out of ONE fused Pallas launch
+    over (A, B); the static :class:`~repro.core.extensions.FusedMask`
+    selects the outputs.  With ``use_fused=False`` each statistic runs its
+    own legacy kernel (the benchmark baseline).  Pairwise dots run on the
+    ``cross_dot`` kernel either way.  Bias stats are cheap row-sums and
+    stay in jnp.
     """
     names = {e.name for e in exts}
     mask = first_order_mask(names)
@@ -211,16 +210,8 @@ def dense_first_order_stats(A, B, exts, cfg: ExtensionConfig, bias: bool):
     # (O(N(a+b))), dot is (AAᵀ)∘(BBᵀ) (O(N²(a+b))), and the moment is the
     # single (A∘A)ᵀ(B∘B) matmul — per_sample_sq_sum routes it to the
     # dedicated sq_matmul kernel below.  Skip the fused kernel entirely.
-    # Under a sharded sweep the pairwise dot needs the *cross-shard* Gram
-    # blocks, which the shard-local fused kernel cannot see — dot drops
-    # out of the launch mask and runs through the gathered Gram einsum
-    # (l2/moment stay fused: they are per-sample/batch-sum local).  The
-    # streaming pair passes (``cross`` set) likewise bypass the fused
-    # launch: only the off-diagonal block is wanted, which the dedicated
-    # cross_dot kernel computes without the two diagonal blocks.
     rank1 = A.shape[1] == 1
-    kmask = FusedMask() if rank1 else (
-        dataclasses.replace(mask, dot=False) if (axes or cross) else mask)
+    kmask = FusedMask() if rank1 else mask
     fused = None
     if cfg.use_kernels and cfg.use_fused and kmask.any():
         from repro.kernels import ops as kops
@@ -248,23 +239,19 @@ def dense_first_order_stats(A, B, exts, cfg: ExtensionConfig, bias: bool):
             out["batch_l2"] = {"w": l2w, "b": jnp.sum(bsum * bsum, -1)}
         else:
             out["batch_l2"] = {"w": l2w}
-    if mask.dot:
-        if fused is not None and kmask.dot:
-            dw = fused["dot"]
-        elif cross is not None and rank1:
-            # Rank-1 cross block: (A1 A2ᵀ) ∘ (B1 B2ᵀ), O(m²(a+b)).
-            dw = ((Af[:cross, 0] @ Af[cross:, 0].T)
-                  * (Bf[:cross, 0] @ Bf[cross:, 0].T))
-        elif cross is not None and cfg.use_kernels:
+    if "batch_dot" in names:
+        A1, A2 = _pair_operands(Af, axes, cross)
+        B1, B2 = _pair_operands(Bf, axes, cross)
+        if rank1:
+            # (A1 A2ᵀ) ∘ (B1 B2ᵀ), O(N·M·(a+b)).
+            dw = (A1[:, 0] @ A2[:, 0].T) * (B1[:, 0] @ B2[:, 0].T)
+        elif cfg.use_kernels:
             from repro.kernels import ops as kops
 
-            dw = kops.cross_dot(Af[:cross], Bf[:cross],
-                                Af[cross:], Bf[cross:])
+            dw = kops.cross_dot(A1, B1, A2, B2)
         else:
-            # Non-fused fallback is the pure-jnp Gram einsum: no standalone
-            # dot kernel ever existed, so that IS the per-extension baseline
-            # (and for R==1 it reduces to the cheap (AAᵀ)∘(BBᵀ) form).
-            dw = per_sample_dots(A, B, shard_axes=axes, cross_split=cross)
+            # The einsum forms [N, M, R, R] (64 GiB at 3C3D conv1, N=128).
+            dw = per_sample_dots(A1, B1, A2, B2)
         if bias:
             bsum = jnp.sum(Bf, axis=1)
             out["batch_dot"] = {"w": dw,
@@ -729,6 +716,26 @@ class Dense(Module):
 # ---------------------------------------------------------------------------
 
 
+def _token_sq(tok, g):
+    """Per-position share of the squared per-sample embedding gradient.
+
+    tok: [N, ...] token ids, g: [..., N, ..., d] rows at those positions.
+    The per-sample gradient row of token id v is h = Σ of the sample's rows
+    at positions holding v; giving each of its ``count`` positions h²/count
+    makes any sum over positions equal the sum over (sample, id) of h² —
+    without the [N, V, d] per-sample scatter, which at LM vocabularies
+    outgrows device memory.  Returns [..., N, T, d] float32 (T = flattened
+    positions per sample).
+    """
+    n = tok.shape[0]
+    t2 = tok.reshape(n, -1)
+    g2 = _f32(g).reshape(g.shape[:g.ndim - tok.ndim - 1] + t2.shape
+                         + g.shape[-1:])
+    eq = (t2[:, :, None] == t2[:, None, :]).astype(jnp.float32)  # [N, T, T]
+    h = jnp.einsum("ntu,...nud->...ntd", eq, g2)
+    return h * h / jnp.sum(eq, axis=-1)[..., None]
+
+
 class Embedding(Module):
     """Token embedding lookup; input int tokens [N, T] -> [N, T, d]."""
 
@@ -758,7 +765,11 @@ class Embedding(Module):
         grads = {"w": gw.astype(params["w"].dtype)}
         stats = {}
         names = {e.name for e in exts}
-        if names & {"batch_grad", "batch_l2", "second_moment", "variance"}:
+        if "second_moment" in names or "variance" in names:
+            stats["_sum_grad2"] = {"w": self._scatter_sq(tok, g)}
+        if "batch_l2" in names:
+            stats["batch_l2"] = {"w": jnp.sum(_token_sq(tok, g), axis=(1, 2))}
+        if names & {"batch_grad", "batch_dot"}:
             def scatter_n(tok_n, g_n):
                 return jnp.zeros((self.vocab, self.d), jnp.float32).at[
                     tok_n.reshape(-1)
@@ -767,10 +778,6 @@ class Embedding(Module):
             pg = jax.vmap(scatter_n)(tok, g)  # [N, V, d] — small-vocab path
             if "batch_grad" in names:
                 stats["batch_grad"] = {"w": pg}
-            if "second_moment" in names or "variance" in names:
-                stats["_sum_grad2"] = {"w": jnp.sum(pg * pg, 0)}
-            if "batch_l2" in names:
-                stats["batch_l2"] = {"w": jnp.sum(pg * pg, axis=(1, 2))}
             if "batch_dot" in names:
                 stats["batch_dot"] = {"w": _pairwise_rows(pg, *_pair_split(cfg))}
         if "kfac" in names or "kflr" in names:
@@ -781,6 +788,14 @@ class Embedding(Module):
     def jac_t_mat(self, params, tape, M):
         return None
 
+    def _scatter_sq(self, tok, g):
+        """Σ over samples (and leading factor axes) of the squared
+        per-sample embedding gradient, [V, d], from per-position rows."""
+        q = _token_sq(tok, g)
+        q = jnp.sum(q.reshape((-1,) + q.shape[-3:]), axis=0)
+        return jnp.zeros((self.vocab, self.d), jnp.float32).at[
+            tok.reshape(-1)].add(q.reshape(-1, self.d))
+
     def curv_backward(self, params, tape, S, exts, cfg, ext_prefix):
         tok = tape
         names = {e.name for e in exts}
@@ -788,13 +803,7 @@ class Embedding(Module):
         diag_name = "diag_ggn_mc" if ext_prefix == "mc" else "diag_ggn"
         kron_name = "kfac" if ext_prefix == "mc" else "kflr"
         if diag_name in names:
-            def scatter_cn(tok_n, S_n):  # tok_n: [T], S_n: [T, d]
-                return jnp.zeros((self.vocab, self.d), jnp.float32).at[
-                    tok_n.reshape(-1)
-                ].add(_f32(S_n).reshape(-1, self.d))
-
-            pg = jax.vmap(lambda Sc: jax.vmap(scatter_cn)(tok, Sc))(S)  # [C,N,V,d]
-            stats[diag_name] = {"w": jnp.sum(pg * pg, axis=(0, 1))}
+            stats[diag_name] = {"w": self._scatter_sq(tok, S)}
         if kron_name in names:
             Sf = _f32(S)
             b_fac = jnp.einsum("cnti,cntj->ij", Sf, Sf) * float(S.shape[2])

@@ -111,8 +111,9 @@ def _product(block_fn, model, params, inputs, targets, loss, v, *,
                         microbatch, total_units=mg)
         return jax.lax.psum(out, axes)
 
-    fn = _shard_map(body, mesh=mesh, in_specs=(P(), batch, batch, P()),
-                    out_specs=P())
+    fn = jax.jit(_shard_map(body, mesh=mesh,
+                            in_specs=(P(), batch, batch, P()),
+                            out_specs=P()))
     return fn(params, inputs, targets, v)
 
 

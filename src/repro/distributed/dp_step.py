@@ -26,7 +26,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import engine as eng
 from repro.distributed.compress import compress_with_ef
@@ -54,11 +53,11 @@ def make_compressed_dp_step(model, loss, opt, mesh, data_axes=("data",)):
         new_ef = jax.tree.map(lambda e: e[None], new_ef)
         return lv, g_sum, new_ef
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(P(), jax.tree.map(lambda _: P(data_axes), 0), batch_spec),
         out_specs=(P(), P(), jax.tree.map(lambda _: P(data_axes), 0)),
-        check_rep=False,
+        check_vma=False,
     )
 
     def step(params, opt_state, ef, batch):

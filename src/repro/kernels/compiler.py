@@ -2,10 +2,7 @@
 
 Every ``pl.pallas_call`` in :mod:`repro.kernels` routes its TPU compiler
 options through :func:`mosaic_params` so there is exactly one code path —
-the non-deprecated ``pltpu.CompilerParams`` dataclass (named
-``TPUCompilerParams`` on older jax) instead of the legacy
-``compiler_params=dict(mosaic=...)`` nested-dict spelling, which newer
-Pallas versions reject.
+the ``pltpu.CompilerParams`` dataclass.
 
 Under the interpreter (the CPU correctness path) no params are built at
 all: Mosaic never runs, and ``pallas_call`` accepts ``None``.
@@ -24,7 +21,5 @@ def mosaic_params(*dimension_semantics: str, interpret: bool = False):
         return None
     from jax.experimental.pallas import tpu as pltpu
 
-    params_cls = getattr(pltpu, "CompilerParams", None)
-    if params_cls is None:  # pre-0.5 spelling
-        params_cls = pltpu.TPUCompilerParams
-    return params_cls(dimension_semantics=tuple(dimension_semantics))
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(dimension_semantics))

@@ -37,14 +37,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, block_k, causal, window, scale,
 
     def body(kb, carry):
         m, l, acc = carry
-        # NB: leading dim must be a Slice, not an int — jax 0.4.x's
-        # interpret-mode discharge rule chokes on scalar indices here.
-        k_blk = pl.load(k_ref, (pl.dslice(0, 1),
-                                pl.dslice(kb * block_k, block_k),
-                                slice(None)))[0].astype(jnp.float32)
-        v_blk = pl.load(v_ref, (pl.dslice(0, 1),
-                                pl.dslice(kb * block_k, block_k),
-                                slice(None)))[0].astype(jnp.float32)
+        k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
+        v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
         logits = jax.lax.dot_general(
             q2, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,

@@ -30,12 +30,14 @@ kernel takes a second, full-width view of the same ``S`` buffer so
 two views alias one array.
 
 Shapes:  A: [N, R, a];  S: [C, N, R, b]   (R = summed sequence/patch axis)
-Outputs: diag [a, b] · kron [b, b] · trace [1, N], all float32.
+Outputs: diag [a, b] · kron [b, b] · trace [N/bn, bn, 1], all float32.
 
-Tiling: grid (b/bb, a/ba, C/C′), class chunks innermost so every
-accumulator sees its revisits consecutively: diag tile (i, j) accumulates
-over c; kron tile (j, ·) accumulates over (i=0, c) runs; trace accumulates
-over everything.  All axes are ``arbitrary`` under Mosaic.
+Tiling: grid (b/bb, a/ba, C/C′, N/bn), class chunks and sample blocks
+innermost so every accumulator sees its revisits consecutively: diag tile
+(i, j) accumulates over (c, n); kron tile (j, ·) accumulates over
+(i=0, c, n) runs; trace accumulates over everything, so its whole column
+stays resident.  Sample blocks keep one grid step inside the scoped VMEM
+at real widths.
 """
 from __future__ import annotations
 
@@ -53,7 +55,8 @@ def _make_kernel(want_diag, want_kron, want_trace):
     need_t = want_diag or want_trace  # A only feeds the contraction tile
 
     def kernel(*refs):
-        j, i, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        j, i = pl.program_id(0), pl.program_id(1)
+        c, nb = pl.program_id(2), pl.program_id(3)
         it = iter(refs)
         a_ref = it.__next__() if need_t else None
         s_ref = it.__next__()
@@ -62,10 +65,10 @@ def _make_kernel(want_diag, want_kron, want_trace):
         kron_ref = it.__next__() if want_kron else None
         tr_ref = it.__next__() if want_trace else None
 
-        s = s_ref[...].astype(jnp.float32)  # [C', N, R, bb]
+        s = s_ref[...].astype(jnp.float32)  # [C', bn, R, bb]
         cc, n, r, bb = s.shape
         if need_t:
-            a = a_ref[...].astype(jnp.float32)  # [N, R, ba]
+            a = a_ref[...].astype(jnp.float32)  # [bn, R, ba]
             # Broadcast A over the class chunk in VMEM (never in HBM) and
             # batch the contraction over the fused (c, n) axis on the MXU.
             arep = jnp.broadcast_to(a[None], (cc,) + a.shape)
@@ -74,29 +77,32 @@ def _make_kernel(want_diag, want_kron, want_trace):
                 s.reshape(cc * n, r, bb),
                 (((1,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
-            )  # [C'·N, ba, bb]
+            )  # [C'·bn, ba, bb]
             t2 = t * t
         if want_diag:
-            @pl.when(c == 0)
+            @pl.when((c == 0) & (nb == 0))
             def _init_diag():
                 diag_ref[...] = jnp.zeros_like(diag_ref)
 
             diag_ref[...] += jnp.sum(t2, axis=0)
         if want_trace:
-            @pl.when((i == 0) & (j == 0) & (c == 0))
+            @pl.when((i == 0) & (j == 0) & (c == 0) & (nb == 0))
             def _init_trace():
                 tr_ref[...] = jnp.zeros_like(tr_ref)
 
-            tr_ref[0] += jnp.sum(t2.reshape(cc, n, -1), axis=(0, 2))
+            per = jnp.sum(t2.reshape(cc, n, *t2.shape[1:]), axis=0)
+            tr_ref[nb] += jnp.sum(jnp.sum(per, axis=1), axis=1,
+                                  keepdims=True)  # [bn, 1]
         if want_kron:
-            @pl.when((i == 0) & (c == 0))
+            @pl.when((i == 0) & (c == 0) & (nb == 0))
             def _init_kron():
                 kron_ref[...] = jnp.zeros_like(kron_ref)
 
-            # SᵀS touches only S — accumulate once per (j, c), not per a-tile.
+            # SᵀS touches only S — accumulate once per (j, c, n), not per
+            # a-tile.
             @pl.when(i == 0)
             def _acc_kron():
-                sf = sf_ref[...].astype(jnp.float32)  # [C', N, R, b]
+                sf = sf_ref[...].astype(jnp.float32)  # [C', bn, R, b]
                 kron_ref[...] += jax.lax.dot_general(
                     s.reshape(-1, bb), sf.reshape(-1, sf.shape[-1]),
                     (((0,), (0,)), ((), ())),
@@ -108,67 +114,71 @@ def _make_kernel(want_diag, want_kron, want_trace):
 
 def fused_second_order_pallas(A, S, *, want_diag=True, want_kron=False,
                               want_trace=False, block_a=128, block_b=128,
-                              class_chunk=1, interpret=True):
+                              class_chunk=1, block_n=8, interpret=True):
     """A: [N, R, a], S: [C, N, R, b] → dict of requested float32 stats.
 
-    Caller is responsible for padding (a, b) to block multiples, (N, R) to
-    sublane multiples and C to a ``class_chunk`` multiple — see the
-    ``fused_second_order`` registry entry in :mod:`repro.kernels.ops`,
-    which owns that policy.
+    Caller is responsible for padding (a, b) to block multiples, N to a
+    ``block_n`` multiple, R to a sublane multiple and C to a
+    ``class_chunk`` multiple — see the ``fused_second_order`` registry
+    entry in :mod:`repro.kernels.ops`, which owns that policy.
     """
     if not (want_diag or want_kron or want_trace):
         raise ValueError("fused_second_order: empty extension mask")
     c, n, r, b = S.shape
     a = A.shape[-1]
-    cc = class_chunk
+    cc, bn = class_chunk, block_n
+    nbs = n // bn
     # Kron-only launches never read A: drop the input and collapse the
     # a-tile grid axis so no step fetches tiles it would discard.
     need_t = want_diag or want_trace
     grid = (pl.cdiv(b, block_b), pl.cdiv(a, block_a) if need_t else 1,
-            pl.cdiv(c, cc))
+            pl.cdiv(c, cc), nbs)
 
     in_specs, inputs = [], []
     if need_t:
         in_specs.append(
-            pl.BlockSpec((n, r, block_a), lambda j, i, k: (0, 0, i)))
+            pl.BlockSpec((bn, r, block_a), lambda j, i, k, p: (p, 0, i)))
         inputs.append(A)
     inputs.append(S)
     in_specs.append(
-        pl.BlockSpec((cc, n, r, block_b), lambda j, i, k: (k, 0, 0, j)))
+        pl.BlockSpec((cc, bn, r, block_b), lambda j, i, k, p: (k, p, 0, j)))
     if want_kron:
         # Second view of the SAME array, full output width (see module doc).
         # Only the i == 0 lane reads it (the kron accumulator fires once per
-        # (j, c), not per a-tile), so for i > 0 the index map parks on the
-        # chunk the i == 0 sweep ended on: an unchanged block index lets
-        # the pipeline elide the re-fetch instead of streaming the
+        # (j, c, n), not per a-tile), so for i > 0 the index map parks on
+        # the block the i == 0 sweep ended on: an unchanged block index
+        # lets the pipeline elide the re-fetch instead of streaming the
         # full-width slab every step.
-        last = pl.cdiv(c, cc) - 1
+        last_c, last_n = pl.cdiv(c, cc) - 1, nbs - 1
         in_specs.append(
-            pl.BlockSpec((cc, n, r, b),
-                         lambda j, i, k: (jnp.where(i == 0, k, last),
-                                          0, 0, 0)))
+            pl.BlockSpec((cc, bn, r, b),
+                         lambda j, i, k, p: (jnp.where(i == 0, k, last_c),
+                                             jnp.where(i == 0, p, last_n),
+                                             0, 0)))
         inputs.append(S)
 
     out_shapes, out_specs, names = [], [], []
     if want_diag:
         out_shapes.append(jax.ShapeDtypeStruct((a, b), jnp.float32))
         out_specs.append(
-            pl.BlockSpec((block_a, block_b), lambda j, i, k: (i, j)))
+            pl.BlockSpec((block_a, block_b), lambda j, i, k, p: (i, j)))
         names.append("diag")
     if want_kron:
         out_shapes.append(jax.ShapeDtypeStruct((b, b), jnp.float32))
-        out_specs.append(pl.BlockSpec((block_b, b), lambda j, i, k: (j, 0)))
+        out_specs.append(
+            pl.BlockSpec((block_b, b), lambda j, i, k, p: (j, 0)))
         names.append("kron")
     if want_trace:
-        out_shapes.append(jax.ShapeDtypeStruct((1, n), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, n), lambda j, i, k: (0, 0)))
+        out_shapes.append(jax.ShapeDtypeStruct((nbs, bn, 1), jnp.float32))
+        out_specs.append(
+            pl.BlockSpec((nbs, bn, 1), lambda j, i, k, p: (0, 0, 0)))
         names.append("trace")
 
     # Grid axes are parallel unless some accumulator spans them: the class
-    # axis always accumulates; the a-axis carries the kron (written once at
-    # i == 0, revisited after) and trace accumulators; the b-axis only the
-    # trace.  Diag-only thus keeps the (parallel, parallel, arbitrary)
-    # schedule of the per-extension ggn_diag kernel it supersedes.
+    # and sample axes always accumulate; the a-axis carries the kron
+    # (written once at i == 0, revisited after) and trace accumulators;
+    # the b-axis only the trace.  Diag-only thus keeps a (parallel,
+    # parallel, arbitrary, arbitrary) schedule.
     sem_j = "arbitrary" if want_trace else "parallel"
     sem_i = "arbitrary" if (want_kron or want_trace) else "parallel"
     outs = pl.pallas_call(
@@ -177,7 +187,7 @@ def fused_second_order_pallas(A, S, *, want_diag=True, want_kron=False,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
-        compiler_params=mosaic_params(sem_j, sem_i, "arbitrary",
+        compiler_params=mosaic_params(sem_j, sem_i, "arbitrary", "arbitrary",
                                       interpret=interpret),
         interpret=interpret,
     )(*inputs)

@@ -7,9 +7,10 @@ keep their own entry points in their modules: their call signatures are
 layer-shaped, not reduction-shaped.)  The registry owns, in one place:
 
 * **Padding** to block multiples.  Feature axes pad to the (shape-clamped)
-  block size, sample/sequence axes to sublane multiples of 8.  Zeros are
-  exact for every reduction here (they contribute nothing to a sum of
-  products), so wrappers pad inputs and slice outputs.
+  block size, sample axes to the sample block, sequence axes to sublane
+  multiples of 8.  Zeros are exact for every reduction here (they
+  contribute nothing to a sum of products), so wrappers pad inputs and
+  slice outputs.
 * **Backend selection** — ``interpret=True`` on CPU (kernel bodies run under
   the Pallas interpreter: the correctness path for this container), compiled
   Mosaic on TPU.  Decided once in :func:`_interpret`, injected into every
@@ -26,12 +27,12 @@ Registered kernels (see :func:`registered`):
 ``per_sample_moment``  Σ_n (A_nᵀB_n)∘² — sequence second moment
 ``batch_l2``           per-sample gradient norms via the Gram trick
 ``ggn_diag``           GGN diagonal from backpropagated factors (Eq. 19/22)
-``fused_first_order``  ONE pass emitting {l2, moment, dot} under a static
-                       extension mask — the mask maps 1:1 onto the
-                       first-order extensions: ``want_l2`` ↔ BatchL2,
-                       ``want_moment`` ↔ SecondMoment/Variance, ``want_dot``
-                       ↔ BatchDot.  Unrequested outputs cost nothing.
-                       A leading group axis batches MoE experts.
+``fused_first_order``  ONE pass emitting {l2, moment} under a static
+                       extension mask: ``want_l2`` ↔ BatchL2,
+                       ``want_moment`` ↔ SecondMoment/Variance (BatchDot
+                       is ``cross_dot``'s).  Unrequested outputs cost
+                       nothing.  A leading group
+                       axis batches MoE experts.
 ``fused_second_order`` ONE pass over (A, S) emitting {diag, kron, trace}
                        under a static mask: ``want_diag`` ↔ DiagGGN(MC),
                        ``want_kron`` ↔ KFLR/KFAC B-factor, ``want_trace`` ↔
@@ -244,59 +245,112 @@ def _clamp_block(block, dim):
     return min(block, max(dim, 8))
 
 
+def _sub(x):
+    """Round up to the 8-row sublane tile."""
+    return -(-x // 8) * 8
+
+
+def _lane(x):
+    """Round up to the 128-wide lane tile."""
+    return -(-x // 128) * 128
+
+
+# Working set one grid step may hold: double-buffered input tiles plus the
+# in-kernel contraction tiles, inside the scoped VMEM Mosaic gives a
+# kernel by default (16 MiB on v5e).
+_STEP_BYTES = 8 << 20
+
+
+def _tile_bytes(r, *widths):
+    """Double-buffered float32 VMEM bytes of one sample's [R, w] input
+    tiles (VMEM pads sublanes to 8 and lanes to 128)."""
+    return 2 * 4 * _sub(r) * sum(_lane(w) for w in widths)
+
+
+def _gram_bytes(ba, bb):
+    """Float32 VMEM bytes of one sample's [ba, bb] contraction tile and
+    its square."""
+    return 2 * 4 * _sub(ba) * _lane(bb)
+
+
+def _sample_block(n, unit_bytes):
+    """Samples per grid step: as many as fit :data:`_STEP_BYTES` at
+    ``unit_bytes`` each, split evenly over the blocks ``n`` needs.  Sized
+    from the batch the kernel is *called* with: the shard-local batch under
+    ``SweepPlan.shard``, the microbatch slice under
+    ``SweepPlan.accumulate``."""
+    fit = max(1, _STEP_BYTES // max(unit_bytes, 1))
+    return -(-n // -(-n // fit))
+
+
 def _auto_block(dim, cap):
-    """Largest even split of ``dim`` into ≤``cap``-wide tiles, sublane-rounded.
+    """Feature tile for ``dim``: the whole (sublane-padded) dim when it
+    fits ``cap``, else the largest even split into lane-aligned tiles.
 
-    Plain ``min(cap, dim)`` pads dims just above a cap multiple by up to
-    ~2x (e.g. 520 → 1024 with cap 512); splitting evenly first keeps the
-    big-tile amortization with ≤ one sublane row of padding per tile
-    (520 → 2×264).
+    Mosaic takes a lane-axis tile only as a multiple of 128 or as the full
+    dimension, so split tiles round up to 128 (576 → 5×128 with cap 128);
+    splitting evenly first keeps padding under one lane tile per tile
+    (520 → 2×384 with cap 512, where plain ``min(cap, dim)`` gives 2×512).
     """
-    if dim <= 8:
-        return 8
+    if dim <= cap:
+        return _sub(dim)
     n_tiles = -(-dim // cap)
-    return min(cap, -(-(-(-dim // n_tiles)) // 8) * 8)
+    return _lane(-(-dim // n_tiles))
 
 
-def _auto_class_chunk(S2, ba, bb, *, mxu_intermediate, kron_view=False):
-    """VMEM-budgeted class chunk, sized from the **local** batch.
-
-    The per-class float32 working set of one grid step: the S tile, plus
-    the [C'·N, ba, bb] MXU contraction intermediate when requested, plus
-    the full-width second S view for the Kronecker output.  The estimate
-    scales with the batch the kernel actually sees — under the
-    batch-sharded sweep lane (``SweepPlan.shard``) that is the
-    *shard-local* N, and under the streaming accumulated lane
-    (``SweepPlan.accumulate``) the *microbatch* slice, so smaller shards
-    or microbatches automatically take larger class chunks (fewer grid
-    steps) inside the same ~4 MiB budget.  The two compose: the shard ×
-    accumulate grid sizes chunks from the shard-local microbatch.
-    """
-    n2, r2 = S2.shape[1], S2.shape[2]
-    per_c = n2 * r2 * bb
-    if mxu_intermediate:
-        per_c += n2 * ba * bb
-    if kron_view:
-        per_c += n2 * r2 * S2.shape[3]
-    return max(1, (1 << 20) // max(per_c, 1))
-
-
-def _pad_factor_pair(A, S, block_a, block_b, interpret):
-    """Shared block-sizing + padding policy for the ``(A, S)`` kernels
-    (``fused_second_order``, ``predictive_var``): A [N, R, a] and
-    S [C, N, R, b] padded to (auto- or caller-chosen) feature blocks and
-    sublane multiples.  Returns ``(A2, S2, ba, bb)``; auto ``class_chunk``
-    budgets live in :func:`_auto_class_chunk` (per-kernel flags select
-    which working-set terms apply)."""
-    a, b = A.shape[-1], S.shape[-1]
+def _feature_blocks(a, b, block_a, block_b, interpret):
+    """(ba, bb) feature tiles: caller-pinned (clamped) or auto-sized —
+    MXU-native 128 caps under Mosaic, 512 under the CPU interpreter,
+    where per-grid-step overhead dominates and bigger tiles amortize it."""
     cap = 512 if interpret else 128
     ba = (_clamp_block(block_a, a) if block_a is not None
           else _auto_block(a, cap))
     bb = (_clamp_block(block_b, b) if block_b is not None
           else _auto_block(b, cap))
-    A2 = _pad_to(_pad_to(_pad_to(A, 2, ba), 1, 8), 0, 8)
-    S2 = _pad_to(_pad_to(_pad_to(S, 3, bb), 2, 8), 1, 8)
-    return A2, S2, ba, bb
+    return ba, bb
+
+
+def _auto_class_chunk(S2, ba, bb, *, bn, mxu_intermediate, kron_view=False):
+    """VMEM-budgeted class chunk for ``bn``-sample grid steps.
+
+    The per-(class, sample) working set of one grid step: the
+    double-buffered S tile (and the A tile it is broadcast against), plus
+    the [C'·bn, ba, bb] MXU contraction tile and its square when
+    requested, plus the full-width second S view for the Kronecker
+    output.  ``bn`` comes from :func:`_sample_block` on the batch the
+    kernel actually sees — the *shard-local* N under the batch-sharded
+    sweep lane (``SweepPlan.shard``), the *microbatch* slice under the
+    streaming accumulated lane (``SweepPlan.accumulate``) — so smaller
+    shards or microbatches take larger class chunks (fewer grid steps)
+    inside the same :data:`_STEP_BYTES` budget.
+    """
+    r, b = S2.shape[2], S2.shape[3]
+    per_c = _tile_bytes(r, bb)
+    if mxu_intermediate:
+        per_c += _tile_bytes(r, ba) + _gram_bytes(ba, bb)
+    if kron_view:
+        per_c += _tile_bytes(r, b)
+    return max(1, _STEP_BYTES // max(per_c * bn, 1))
+
+
+def _pad_factor_pair(A, S, block_a, block_b, interpret, *, kron_view=False):
+    """Shared block-sizing + padding policy for the ``(A, S)`` kernels
+    (``fused_second_order``, ``predictive_var``): A [N, R, a] and
+    S [C, N, R, b] padded to (auto- or caller-chosen) feature blocks, a
+    sample-block multiple and a sublane multiple of R.  Returns
+    ``(A2, S2, ba, bb, bn)``; auto ``class_chunk`` budgets live in
+    :func:`_auto_class_chunk` (per-kernel flags select which working-set
+    terms apply)."""
+    a, b = A.shape[-1], S.shape[-1]
+    ba, bb = _feature_blocks(a, b, block_a, block_b, interpret)
+    r = A.shape[1]
+    unit = _tile_bytes(r, ba, bb) + _gram_bytes(ba, bb)
+    if kron_view:
+        unit += _tile_bytes(r, b)
+    bn = _sample_block(A.shape[0], unit)
+    A2 = _pad_to(_pad_to(_pad_to(A, 2, ba), 1, 8), 0, bn)
+    S2 = _pad_to(_pad_to(_pad_to(S, 3, bb), 2, 8), 1, bn)
+    return A2, S2, ba, bb, bn
 
 
 # ---------------------------------------------------------------------------
@@ -310,9 +364,9 @@ def _sq_matmul(A, B, *, block_a=128, block_b=128, block_n=256,
     """C = (A∘A)ᵀ(B∘B): A [N, a], B [N, b] → [a, b]."""
     a, b = A.shape[1], B.shape[1]
     ba, bb = _clamp_block(block_a, a), _clamp_block(block_b, b)
-    A2 = _pad_to(_pad_to(A, 1, ba), 0, 8)
-    B2 = _pad_to(_pad_to(B, 1, bb), 0, 8)
-    bn = min(block_n, A2.shape[0])
+    bn = min(block_n, _sub(A.shape[0]))
+    A2 = _pad_to(_pad_to(A, 1, ba), 0, bn)
+    B2 = _pad_to(_pad_to(B, 1, bb), 0, bn)
     out = sq_matmul_pallas(A2, B2, block_a=ba, block_b=bb, block_n=bn,
                            interpret=interpret)
     return out[:a, :b]
@@ -354,37 +408,32 @@ def _ggn_diag(A, S, *, block_a=128, block_b=128, interpret=True):
 
 @register("fused_first_order", ref=ref.fused_first_order)
 def _fused_first_order(A, B, *, want_l2=True, want_moment=False,
-                       want_dot=False, block_a=None, block_b=None,
-                       interpret=True):
+                       block_a=None, block_b=None, interpret=True):
     """One pass over (A, B) emitting the masked first-order stats.
 
     A: [E, N, R, a], B: [E, N, R, b] → dict of
-    l2 [E, N] / moment [E, a, b] / dot [E, N, N] (requested keys only).
-    Zero-padding N and R is exact; padded l2 rows and dot rows/cols are
-    sliced off, moment is unaffected.
+    l2 [E, N] / moment [E, a, b] (requested keys only).  Zero-padding N
+    and R is exact; padded l2 rows are sliced off, moment is unaffected.
+    Pairwise dots (BatchDot) are the ``cross_dot`` kernel's.
 
-    Default blocks are backend-aware (``None`` = auto): MXU-native 128 under
-    Mosaic; 512 under the CPU interpreter, where per-grid-step overhead
-    dominates and bigger tiles amortize it.
+    Default blocks are backend-aware (``None`` = auto, see
+    :func:`_feature_blocks`); samples are blocked to the step budget.
     """
+    if not (want_l2 or want_moment):
+        raise ValueError("fused_first_order: empty extension mask")
     e, n, r, a = A.shape
     b = B.shape[-1]
-    cap = 512 if interpret else 128
-    ba = (_clamp_block(block_a, a) if block_a is not None
-          else _auto_block(a, cap))
-    bb = (_clamp_block(block_b, b) if block_b is not None
-          else _auto_block(b, cap))
-    A2 = _pad_to(_pad_to(_pad_to(A, 3, ba), 2, 8), 1, 8)
-    B2 = _pad_to(_pad_to(_pad_to(B, 3, bb), 2, 8), 1, 8)
+    ba, bb = _feature_blocks(a, b, block_a, block_b, interpret)
+    bn = _sample_block(n, _tile_bytes(r, ba, bb) + _gram_bytes(ba, bb))
+    A2 = _pad_to(_pad_to(_pad_to(A, 3, ba), 2, 8), 1, bn)
+    B2 = _pad_to(_pad_to(_pad_to(B, 3, bb), 2, 8), 1, bn)
     out = fused_first_order_pallas(
-        A2, B2, want_l2=want_l2, want_moment=want_moment, want_dot=want_dot,
-        block_a=ba, block_b=bb, interpret=interpret)
+        A2, B2, want_l2=want_l2, want_moment=want_moment,
+        block_a=ba, block_b=bb, block_n=bn, interpret=interpret)
     if "l2" in out:
-        out["l2"] = out["l2"][:, :n]
+        out["l2"] = out["l2"].reshape(e, -1)[:, :n]
     if "moment" in out:
         out["moment"] = out["moment"][:, :a, :b]
-    if "dot" in out:
-        out["dot"] = out["dot"][:, :n, :n]
     return out
 
 
@@ -402,18 +451,20 @@ def _cross_dot(A1, B1, A2, B2, *, block_a=None, block_b=None,
     e, n1, r, a = A1.shape
     n2 = A2.shape[1]
     b = B1.shape[-1]
-    cap = 512 if interpret else 128
-    ba = (_clamp_block(block_a, a) if block_a is not None
-          else _auto_block(a, cap))
-    bb = (_clamp_block(block_b, b) if block_b is not None
-          else _auto_block(b, cap))
+    ba, bb = _feature_blocks(a, b, block_a, block_b, interpret)
+    # Two sample sets share one step's budget.
+    unit = 2 * (_tile_bytes(r, ba, bb) + _gram_bytes(ba, bb))
+    bn1, bn2 = _sample_block(n1, unit), _sample_block(n2, unit)
 
-    def prep(x, blk):
-        return _pad_to(_pad_to(_pad_to(x, 3, blk), 2, 8), 1, 8)
+    def prep(x, blk, bn):
+        return _pad_to(_pad_to(_pad_to(x, 3, blk), 2, 8), 1, bn)
 
-    out = cross_dot_pallas(prep(A1, ba), prep(B1, bb),
-                           prep(A2, ba), prep(B2, bb),
-                           block_a=ba, block_b=bb, interpret=interpret)
+    out = cross_dot_pallas(prep(A1, ba, bn1), prep(B1, bb, bn1),
+                           prep(A2, ba, bn2), prep(B2, bb, bn2),
+                           block_a=ba, block_b=bb, block_n1=bn1,
+                           block_n2=bn2, interpret=interpret)
+    nb1, nb2 = out.shape[1:3]
+    out = out.transpose(0, 1, 3, 2, 4).reshape(e, nb1 * bn1, nb2 * bn2)
     return out[:, :n1, :n2]
 
 
@@ -430,29 +481,31 @@ def _fused_second_order(A, S, *, want_diag=True, want_kron=False,
     columns likewise.
 
     ``class_chunk`` bounds the VMEM-resident working set per grid step
-    (``None`` = auto: the whole class axis when it fits a ~4 MiB float32
-    budget, chunked otherwise) — the grid folds the class axis so the
-    per-class contribution tensor never materializes.
+    (``None`` = auto: the whole class axis when it fits the step budget,
+    chunked otherwise) — the grid folds the class axis so the per-class
+    contribution tensor never materializes.  Samples are blocked to the
+    same budget.
     """
     c, n, r, b = S.shape
     a = A.shape[-1]
-    A2, S2, ba, bb = _pad_factor_pair(A, S, block_a, block_b, interpret)
+    A2, S2, ba, bb, bn = _pad_factor_pair(A, S, block_a, block_b, interpret,
+                                          kron_view=want_kron)
     if class_chunk is None:
         class_chunk = _auto_class_chunk(
-            S2, ba, bb, mxu_intermediate=want_diag or want_trace,
+            S2, ba, bb, bn=bn, mxu_intermediate=want_diag or want_trace,
             kron_view=want_kron)
     cc = max(1, min(class_chunk, c))
     S2 = _pad_to(S2, 0, cc)
     out = fused_second_order_pallas(
         A2, S2, want_diag=want_diag, want_kron=want_kron,
         want_trace=want_trace, block_a=ba, block_b=bb, class_chunk=cc,
-        interpret=interpret)
+        block_n=bn, interpret=interpret)
     if "diag" in out:
         out["diag"] = out["diag"][:a, :b]
     if "kron" in out:
         out["kron"] = out["kron"][:b, :b]
     if "trace" in out:
-        out["trace"] = out["trace"][0, :n]
+        out["trace"] = out["trace"].reshape(-1)[:n]
     return out
 
 
@@ -468,23 +521,23 @@ def _predictive_var(A, S, *maybe_sigma, want_sigma=False, block_a=None,
     columns are sliced off.
 
     ``class_chunk`` bounds the VMEM-resident working set per grid step
-    (``None`` = auto, same ~4 MiB float32 budget as ``fused_second_order``).
+    (``None`` = auto, same step budget as ``fused_second_order``).
     """
     c, n, r, b = S.shape
-    a = A.shape[-1]
-    A2, S2, ba, bb = _pad_factor_pair(A, S, block_a, block_b, interpret)
+    A2, S2, ba, bb, bn = _pad_factor_pair(A, S, block_a, block_b, interpret)
     Sigma2 = None
     if want_sigma:
         (Sigma,) = maybe_sigma
         Sigma2 = _pad_to(_pad_to(Sigma, 1, bb), 0, ba)
     if class_chunk is None:
-        class_chunk = _auto_class_chunk(S2, ba, bb, mxu_intermediate=True)
+        class_chunk = _auto_class_chunk(S2, ba, bb, bn=bn,
+                                        mxu_intermediate=True)
     cc = max(1, min(class_chunk, c))
     S2 = _pad_to(S2, 0, cc)
     out = predictive_var_pallas(
         A2, S2, Sigma2, block_a=ba, block_b=bb, class_chunk=cc,
-        interpret=interpret)
-    return out[:c, :n]
+        block_n=bn, interpret=interpret)
+    return out.reshape(out.shape[0], -1)[:c, :n]
 
 
 # ---------------------------------------------------------------------------
@@ -537,7 +590,7 @@ def predictive_var(A, S, Sigma=None, block_a=None, block_b=None,
                     class_chunk=class_chunk)
 
 
-def fused_first_order(A, B, want_l2=True, want_moment=False, want_dot=False,
+def fused_first_order(A, B, want_l2=True, want_moment=False,
                       block_a=None, block_b=None):
     """Fused first-order stats; A/B may be [N, R, a] (a leading group axis
     of 1 is added and stripped) or [E, N, R, a]."""
@@ -545,8 +598,7 @@ def fused_first_order(A, B, want_l2=True, want_moment=False, want_dot=False,
     if squeeze:
         A, B = A[None], B[None]
     out = dispatch("fused_first_order", A, B, want_l2=want_l2,
-                   want_moment=want_moment, want_dot=want_dot,
-                   block_a=block_a, block_b=block_b)
+                   want_moment=want_moment, block_a=block_a, block_b=block_b)
     if squeeze:
         out = {k: v[0] for k, v in out.items()}
     return out

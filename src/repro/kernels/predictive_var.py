@@ -27,12 +27,14 @@ of traffic); here each ``(a, b)`` tile of the contraction lives only in
 VMEM/registers on its way into the ``[C, N]`` accumulator.
 
 Shapes:  A: [N, R, a];  S: [C, N, R, b];  Sigma: [a, b] (optional)
-Output:  var [C, N] float32.
+Output:  var [C, N/bn, bn, 1] float32 (a column per class and sample
+block, so every output tile spans the array's own minor dims).
 
-Tiling: grid (C/C′, a/ba, b/bb) — class chunks outermost so each output
-block ``var[c-chunk]`` stays resident across its whole (i, j) accumulation
-run; the (a, b) tile axes are ``arbitrary`` under Mosaic, the class axis is
-``parallel`` (distinct output blocks).
+Tiling: grid (C/C′, N/bn, a/ba, b/bb) — class chunks and sample blocks
+outermost so each output block ``var[c-chunk, n-block]`` stays resident
+across its whole (i, j) accumulation run; the (a, b) tile axes are
+``arbitrary`` under Mosaic, the class and sample axes ``parallel``
+(distinct output blocks).
 """
 from __future__ import annotations
 
@@ -50,10 +52,10 @@ def _make_kernel(want_sigma):
         s_ref = next(it)
         sig_ref = next(it) if want_sigma else None
         var_ref = next(it)
-        i, j = pl.program_id(1), pl.program_id(2)
+        i, j = pl.program_id(2), pl.program_id(3)
 
-        s = s_ref[...].astype(jnp.float32)      # [C', N, R, bb]
-        a = a_ref[...].astype(jnp.float32)      # [N, R, ba]
+        s = s_ref[...].astype(jnp.float32)      # [C', bn, R, bb]
+        a = a_ref[...].astype(jnp.float32)      # [bn, R, ba]
         cc, n, r, bb = s.shape
         # Broadcast A over the class chunk in VMEM (never in HBM) and batch
         # the r-contraction over the fused (c, n) axis on the MXU.
@@ -63,56 +65,60 @@ def _make_kernel(want_sigma):
             s.reshape(cc * n, r, bb),
             (((1,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        )                                        # [C'·N, ba, bb]
+        )                                        # [C'·bn, ba, bb]
         t2 = t * t
         if want_sigma:
             t2 = t2 * sig_ref[...].astype(jnp.float32)[None]
-        contrib = jnp.sum(t2, axis=(1, 2)).reshape(cc, n)
+        contrib = jnp.sum(jnp.sum(t2.reshape(cc, n, *t2.shape[1:]), axis=2),
+                          axis=2, keepdims=True)  # [C', bn, 1]
 
         @pl.when((i == 0) & (j == 0))
         def _init():
             var_ref[...] = jnp.zeros_like(var_ref)
 
-        var_ref[...] += contrib
+        var_ref[:, 0] += contrib
 
     return kernel
 
 
 def predictive_var_pallas(A, S, Sigma=None, *, block_a=128, block_b=128,
-                          class_chunk=1, interpret=True):
-    """A: [N, R, a], S: [C, N, R, b] (+ Sigma [a, b]) → var [C, N] float32.
+                          class_chunk=1, block_n=8, interpret=True):
+    """A: [N, R, a], S: [C, N, R, b] (+ Sigma [a, b]) →
+    var [C, N/bn, bn, 1] float32.
 
-    Caller is responsible for padding (a, b) to block multiples, (N, R) to
-    sublane multiples and C to a ``class_chunk`` multiple — see the
-    ``predictive_var`` registry entry in :mod:`repro.kernels.ops`, which
-    owns that policy.  Zero padding is exact everywhere: padded A/S rows
-    and columns contribute zero to the contraction tile, so their squared
-    entries vanish regardless of Sigma's padding.
+    Caller is responsible for padding (a, b) to block multiples, N to a
+    ``block_n`` multiple, R to a sublane multiple and C to a
+    ``class_chunk`` multiple — see the ``predictive_var`` registry entry
+    in :mod:`repro.kernels.ops`, which owns that policy.  Zero padding is
+    exact everywhere: padded A/S rows and columns contribute zero to the
+    contraction tile, so their squared entries vanish regardless of
+    Sigma's padding.
     """
     c, n, r, b = S.shape
     a = A.shape[-1]
-    cc = class_chunk
+    cc, bn = class_chunk, block_n
     want_sigma = Sigma is not None
-    grid = (pl.cdiv(c, cc), pl.cdiv(a, block_a), pl.cdiv(b, block_b))
+    grid = (pl.cdiv(c, cc), n // bn, pl.cdiv(a, block_a),
+            pl.cdiv(b, block_b))
 
     in_specs = [
-        pl.BlockSpec((n, r, block_a), lambda k, i, j: (0, 0, i)),
-        pl.BlockSpec((cc, n, r, block_b), lambda k, i, j: (k, 0, 0, j)),
+        pl.BlockSpec((bn, r, block_a), lambda k, p, i, j: (p, 0, i)),
+        pl.BlockSpec((cc, bn, r, block_b), lambda k, p, i, j: (k, p, 0, j)),
     ]
     inputs = [A, S]
     if want_sigma:
         in_specs.append(
-            pl.BlockSpec((block_a, block_b), lambda k, i, j: (i, j)))
+            pl.BlockSpec((block_a, block_b), lambda k, p, i, j: (i, j)))
         inputs.append(Sigma)
 
-    out = pl.pallas_call(
+    return pl.pallas_call(
         _make_kernel(want_sigma),
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((cc, n), lambda k, i, j: (k, 0)),
-        out_shape=jax.ShapeDtypeStruct((c, n), jnp.float32),
-        compiler_params=mosaic_params("parallel", "arbitrary", "arbitrary",
-                                      interpret=interpret),
+        out_specs=pl.BlockSpec((cc, 1, bn, 1),
+                               lambda k, p, i, j: (k, p, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((c, n // bn, bn, 1), jnp.float32),
+        compiler_params=mosaic_params("parallel", "parallel", "arbitrary",
+                                      "arbitrary", interpret=interpret),
         interpret=interpret,
     )(*inputs)
-    return out

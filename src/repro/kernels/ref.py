@@ -90,11 +90,11 @@ def predictive_var(A, S, Sigma=None):
     return jnp.sum(t2, axis=(2, 3))
 
 
-def fused_first_order(A, B, want_l2=True, want_moment=False, want_dot=False):
+def fused_first_order(A, B, want_l2=True, want_moment=False):
     """Oracle for the fused kernel: materialize G[n] = A_nᵀB_n, reduce.
 
     A: [E, N, R, a], B: [E, N, R, b] → dict of requested stats
-    (l2 [E, N] · moment [E, a, b] · dot [E, N, N]), all float32.
+    (l2 [E, N] · moment [E, a, b]), all float32.
     """
     Af, Bf = A.astype(jnp.float32), B.astype(jnp.float32)
     g = jnp.einsum("enra,enrb->enab", Af, Bf)
@@ -103,6 +103,4 @@ def fused_first_order(A, B, want_l2=True, want_moment=False, want_dot=False):
         out["l2"] = jnp.sum(g * g, axis=(2, 3))
     if want_moment:
         out["moment"] = jnp.sum(g * g, axis=1)
-    if want_dot:
-        out["dot"] = jnp.einsum("enab,emab->enm", g, g)
     return out

@@ -26,14 +26,11 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, *, chunk, n_chunks):
     u = u_ref[0].astype(jnp.float32)
 
     def body(c, S):
-        # Leading dim must be a Slice, not an int — jax 0.4.x's
-        # interpret-mode discharge rule chokes on scalar indices.
-        sl = (pl.dslice(0, 1), pl.dslice(c * chunk, chunk), slice(None))
-        rc = pl.load(r_ref, sl)[0].astype(jnp.float32)
-        kc = pl.load(k_ref, sl)[0].astype(jnp.float32)
-        vc = pl.load(v_ref, sl)[0].astype(jnp.float32)
-        lwc = jnp.clip(pl.load(lw_ref, sl)[0].astype(jnp.float32),
-                       -60.0, -1e-6)
+        sl = (0, pl.ds(c * chunk, chunk), slice(None))
+        rc = r_ref[sl].astype(jnp.float32)
+        kc = k_ref[sl].astype(jnp.float32)
+        vc = v_ref[sl].astype(jnp.float32)
+        lwc = jnp.clip(lw_ref[sl].astype(jnp.float32), -60.0, -1e-6)
         P = jnp.cumsum(lwc, axis=0)
         E = P - lwc
         r_t = rc * jnp.exp(E)
@@ -46,7 +43,7 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, *, chunk, n_chunks):
         y = y + diag[:, None] * vc
         y = y + jax.lax.dot_general(r_t, S, (((1,), (0,)), ((), ())),
                                     preferred_element_type=jnp.float32)
-        pl.store(y_ref, sl, y[None].astype(y_ref.dtype))
+        y_ref[sl] = y.astype(y_ref.dtype)
         decay_end = jnp.exp(P[-1])
         k_end = kc * jnp.exp(P[-1][None] - P)
         S_new = decay_end[:, None] * S + jax.lax.dot_general(

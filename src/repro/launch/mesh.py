@@ -11,17 +11,9 @@ import jax
 
 
 def _mesh(shape, axes):
-    """Version-compat mesh constructor.
-
-    ``jax.sharding.AxisType`` landed after jax 0.4.x; on older versions
-    (e.g. the pinned 0.4.37 CI environment) every axis is implicitly Auto,
-    so dropping the kwarg is behavior-preserving.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """Mesh over the local devices with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

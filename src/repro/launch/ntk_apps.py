@@ -16,6 +16,7 @@ import argparse
 import jax
 import jax.numpy as jnp
 
+from repro.launch.cache import enable_compile_cache
 from repro import obs
 from repro.configs import papernets
 from repro.core import CrossEntropyLoss, ExtensionConfig
@@ -63,6 +64,7 @@ def main():
     ap.add_argument("--trace-jsonl", default=None,
                     help="record the obs span trace to this JSONL file")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.trace_jsonl:
         obs.enable(trace_jsonl=args.trace_jsonl)

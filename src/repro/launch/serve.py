@@ -13,6 +13,7 @@ import argparse
 import jax
 import jax.numpy as jnp
 
+from repro.launch.cache import enable_compile_cache
 from repro.configs import get_config
 from repro.nn.models import build_model
 from repro.serve.engine import ServeConfig, generate, generate_whisper
@@ -77,6 +78,7 @@ def main():
                     help="next-token mean + Laplace predictive variance "
                          "instead of sampled tokens")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full:

@@ -9,6 +9,7 @@ full config with the production mesh (--full --mesh single|multi).
 import argparse
 import dataclasses
 
+from repro.launch.cache import enable_compile_cache
 from repro import obs
 from repro.configs import SHAPES, get_config
 from repro.core import DiagGGNMC, ExtensionConfig, KFAC, Variance
@@ -71,6 +72,7 @@ def main():
                          "into this directory (view with TensorBoard / "
                          "Perfetto)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.trace_jsonl or args.metrics_report or args.profile_dir:
         obs.enable(trace_jsonl=args.trace_jsonl)

@@ -245,6 +245,34 @@ class MaxPool2d(Module):
             "VALID",
         )
 
+    def jac_t_mat(self, params, tape, M):
+        """Route each column of M to the first maximum of its window.
+
+        Non-overlapping windows (stride == size) take a one-hot mask of
+        each window's argmax, built once from x and broadcast over the
+        leading column axis.  The generic ``vmap(vjp)`` form lowers to a
+        batched ``select_and_scatter`` over a column-wide copy of x, which
+        XLA on the TPU v5e returned as zeros for 3C3D's first pool when
+        the exact and MC GGN sweeps shared one program (see
+        ``tools/maxpool_repro.py``).  Ties go to the first element in
+        row-major window order, as ``select_and_scatter`` resolves them.
+        """
+        if self.stride != self.size:
+            return super().jac_t_mat(params, tape, M)
+        x = tape
+        s = self.size
+        n, h, w, ch = x.shape
+        ho, wo = h // s, w // s
+        win = x[:, :ho * s, :wo * s].reshape(n, ho, s, wo, s, ch)
+        win = jnp.moveaxis(win, 3, 2).reshape(n, ho, wo, s * s, ch)
+        first = jax.nn.one_hot(jnp.argmax(win, axis=3), s * s, axis=3,
+                               dtype=M.dtype)
+        out = first[None] * M[:, :, :, :, None, :]
+        out = out.reshape(M.shape[:4] + (s, s, ch))
+        out = jnp.moveaxis(out, 4, 3).reshape(M.shape[:2] + (ho * s, wo * s, ch))
+        return jnp.pad(out, ((0, 0), (0, 0), (0, h - ho * s), (0, w - wo * s),
+                             (0, 0)))
+
 
 class Flatten(Module):
     def apply(self, params, x):

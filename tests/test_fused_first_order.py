@@ -37,6 +37,13 @@ def _pair(e, n, r, a, b, dtype=jnp.float32, seed=0):
             _rand(jax.random.fold_in(k, 1), (e, n, r, b), dtype))
 
 
+def _dots(A, B):
+    """Pairwise dots of the materialized per-sample gradients [E, N, N]."""
+    g = jnp.einsum("enra,enrb->enab", A.astype(jnp.float32),
+                   B.astype(jnp.float32))
+    return jnp.einsum("enab,emab->enm", g, g)
+
+
 # --- kernel vs oracle parity -------------------------------------------------
 
 @pytest.mark.parametrize("dtype", DTYPES)
@@ -48,10 +55,9 @@ def _pair(e, n, r, a, b, dtype=jnp.float32, seed=0):
 ])
 def test_fused_parity_all_outputs(e, n, r, a, b, dtype):
     A, B = _pair(e, n, r, a, b, dtype, seed=e * n + a)
-    got = ops.fused_first_order(A, B, want_l2=True, want_moment=True,
-                                want_dot=True)
-    want = ref.fused_first_order(A, B, want_l2=True, want_moment=True,
-                                 want_dot=True)
+    got = ops.fused_first_order(A, B, want_l2=True, want_moment=True)
+    want = ref.fused_first_order(A, B, want_l2=True, want_moment=True)
+    got["dot"], want["dot"] = ops.cross_dot(A, B, A, B), _dots(A, B)
     for key in ("l2", "moment", "dot"):
         np.testing.assert_allclose(np.asarray(got[key]),
                                    np.asarray(want[key]), **TOL[dtype])
@@ -64,10 +70,10 @@ def test_fused_parity_multi_tile(block_a, block_b):
     the auto block policy would otherwise make every test single-tile."""
     A, B = _pair(2, 5, 3, 50, 41, seed=7)
     got = ops.fused_first_order(A, B, want_l2=True, want_moment=True,
-                                want_dot=True, block_a=block_a,
-                                block_b=block_b)
-    want = ref.fused_first_order(A, B, want_l2=True, want_moment=True,
-                                 want_dot=True)
+                                block_a=block_a, block_b=block_b)
+    want = ref.fused_first_order(A, B, want_l2=True, want_moment=True)
+    got["dot"] = ops.cross_dot(A, B, A, B, block_a=block_a, block_b=block_b)
+    want["dot"] = _dots(A, B)
     for key in ("l2", "moment", "dot"):
         np.testing.assert_allclose(np.asarray(got[key]),
                                    np.asarray(want[key]),
@@ -75,18 +81,15 @@ def test_fused_parity_multi_tile(block_a, block_b):
 
 
 def test_fused_all_mask_combinations():
-    """Every 2^3 mask: requested keys present and correct, others absent."""
+    """Every 2^2 mask: requested keys present and correct, others absent."""
     A, B = _pair(1, 5, 3, 19, 11)
-    for wl, wm, wd in itertools.product([False, True], repeat=3):
-        if not (wl or wm or wd):
+    for wl, wm in itertools.product([False, True], repeat=2):
+        if not (wl or wm):
             with pytest.raises(ValueError):
-                ops.fused_first_order(A, B, want_l2=False, want_moment=False,
-                                      want_dot=False)
+                ops.fused_first_order(A, B, want_l2=False, want_moment=False)
             continue
-        got = ops.fused_first_order(A, B, want_l2=wl, want_moment=wm,
-                                    want_dot=wd)
-        want = ref.fused_first_order(A, B, want_l2=wl, want_moment=wm,
-                                     want_dot=wd)
+        got = ops.fused_first_order(A, B, want_l2=wl, want_moment=wm)
+        want = ref.fused_first_order(A, B, want_l2=wl, want_moment=wm)
         assert set(got) == set(want)
         for key in got:
             np.testing.assert_allclose(np.asarray(got[key]),
@@ -95,11 +98,12 @@ def test_fused_all_mask_combinations():
 
 
 def test_fused_internal_consistency():
-    """diag(dot) == l2, and moment == Σ_n of the per-sample outer squares."""
+    """diag(cross_dot) == l2, and moment == Σ_n of the per-sample outer
+    squares."""
     A, B = _pair(1, 7, 4, 23, 13)
-    got = ops.fused_first_order(A, B, want_l2=True, want_moment=True,
-                                want_dot=True)
-    np.testing.assert_allclose(np.asarray(jnp.diagonal(got["dot"][0])),
+    got = ops.fused_first_order(A, B, want_l2=True, want_moment=True)
+    dot = ops.cross_dot(A, B, A, B)
+    np.testing.assert_allclose(np.asarray(jnp.diagonal(dot[0])),
                                np.asarray(got["l2"][0]), rtol=3e-5, atol=3e-5)
     g = jnp.einsum("nra,nrb->nab", A[0], B[0])
     np.testing.assert_allclose(np.asarray(got["moment"][0]),
@@ -112,10 +116,9 @@ def test_fused_internal_consistency():
        b=st.integers(1, 33), seed=st.integers(0, 2 ** 16))
 def test_fused_hypothesis_parity(n, r, a, b, seed):
     A, B = _pair(1, n, r, a, b, seed=seed)
-    got = ops.fused_first_order(A, B, want_l2=True, want_moment=True,
-                                want_dot=True)
-    want = ref.fused_first_order(A, B, want_l2=True, want_moment=True,
-                                 want_dot=True)
+    got = ops.fused_first_order(A, B, want_l2=True, want_moment=True)
+    want = ref.fused_first_order(A, B, want_l2=True, want_moment=True)
+    got["dot"], want["dot"] = ops.cross_dot(A, B, A, B), _dots(A, B)
     for key in got:
         np.testing.assert_allclose(np.asarray(got[key]),
                                    np.asarray(want[key]),
@@ -150,7 +153,7 @@ def test_registry_jit_cache_is_config_keyed():
         np.asarray(got["l2"]),
         np.asarray(ref.fused_first_order(A2, B2, want_l2=True)["l2"]),
         rtol=3e-5, atol=3e-5)
-    ops.fused_first_order(A, B, want_l2=True, want_dot=True)  # new static opts
+    ops.fused_first_order(A, B, want_l2=True, want_moment=True)  # new opts
     stats = ops.cache_stats()
     assert stats["total"] == n0 + 1
     assert stats["fused_first_order"] >= 2
@@ -163,21 +166,20 @@ ALL_FIRST = (BatchGrad, BatchL2, SecondMoment, Variance, BatchDot)
 
 def test_sweep_plan_fused_mask():
     plan = plan_sweeps(ALL_FIRST)
-    assert plan.fused_mask.l2 and plan.fused_mask.moment and plan.fused_mask.dot
+    assert plan.fused_mask.l2 and plan.fused_mask.moment
     assert not plan.fused_active  # default config: jnp path
     assert "fused_first_order=None" in plan.describe()
     active = plan_sweeps(ALL_FIRST, ExtensionConfig(use_kernels=True))
     assert active.fused_active
-    assert "fused_first_order=['l2', 'moment', 'dot']" in active.describe()
+    assert "fused_first_order=['l2', 'moment']" in active.describe()
     legacy = plan_sweeps(ALL_FIRST, ExtensionConfig(use_kernels=True,
                                                     use_fused=False))
     assert not legacy.fused_active
     plan = plan_sweeps((BatchGrad,))
     assert not plan.fused_mask.any()
     mask = first_order_mask({"variance"})
-    assert mask.moment and not (mask.l2 or mask.dot)
-    assert mask.wants() == dict(want_l2=False, want_moment=True,
-                                want_dot=False)
+    assert mask.moment and not mask.l2
+    assert mask.wants() == dict(want_l2=False, want_moment=True)
 
 
 def _paper_nets():

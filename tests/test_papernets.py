@@ -100,3 +100,25 @@ def test_kflr_kfac_factor_shapes(conv_setup):
     assert f["w"]["B"].shape == (32, 32)
     f2 = res["kfac"][0]
     assert f2["w"]["B"].shape == (32, 32)
+
+
+@pytest.mark.parametrize("hw,size,stride", [((8, 8), 2, None),
+                                            ((9, 7), 2, None),
+                                            ((9, 9), 3, None),
+                                            ((8, 8), 3, 2)])
+def test_maxpool_jac_t_mat_matches_vjp(hw, size, stride):
+    """MaxPool2d's one-hot transpose equals the generic vmap(vjp) one,
+    ties (first max in row-major window order) and ragged edges included;
+    overlapping windows fall back to the generic form."""
+    from repro.core.module import Module
+    from repro.nn.layers import MaxPool2d
+
+    mp = MaxPool2d(size, stride)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(0))
+    x = jax.nn.relu(jax.random.normal(k1, (3,) + hw + (5,)))
+    x = x.at[0, :size, :size, 0].set(1.5)  # a tie inside one window
+    M = jax.random.normal(k2, (4,) + mp.apply(None, x).shape)
+    got = mp.jac_t_mat(None, x, M)
+    want = Module.jac_t_mat(mp, None, x, M)
+    assert got.shape == want.shape == (4,) + x.shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

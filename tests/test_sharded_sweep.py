@@ -197,7 +197,6 @@ def test_sharded_masked_loss_scaling(setup):
 def test_local_loss_and_grad_is_unreduced_seam(setup):
     """psum(local contributions) == the engine's global gradient — the
     compressed-DP step's compression seam."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     model, params, x, y = setup
@@ -208,9 +207,9 @@ def test_local_loss_and_grad_is_unreduced_seam(setup):
         lv, g = local_loss_and_grad(model, p, xx, yy, loss, ("data",))
         return lv, jax.tree.map(lambda a: jax.lax.psum(a, ("data",)), g)
 
-    lv, g = shard_map(body, mesh=mesh,
-                      in_specs=(P(), P(("data",)), P(("data",))),
-                      out_specs=(P(), P()), check_rep=False)(params, x, y)
+    lv, g = jax.shard_map(body, mesh=mesh,
+                          in_specs=(P(), P(("data",)), P(("data",))),
+                          out_specs=(P(), P()), check_vma=False)(params, x, y)
     ref = run(model, params, x, y, loss)
     np.testing.assert_allclose(np.asarray(lv), np.asarray(ref.loss),
                                rtol=1e-6)
