@@ -194,10 +194,8 @@ class SweepPlan:
         plan-object counterpart of :func:`run`, giving all three lanes
         (monolithic / sharded / accumulated) one calling convention."""
         extensions = tuple(by_name(n) for n in sorted(self.names))
-        with obs.span("engine/sweep", lane="monolithic",
-                      extensions=",".join(sorted(self.names))):
-            return run(model, params, inputs, targets, loss,
-                       extensions=extensions, cfg=cfg, rng=rng)
+        return run(model, params, inputs, targets, loss,
+                   extensions=extensions, cfg=cfg, rng=rng)
 
 
 def plan_sweeps(extensions: Sequence[Extension],
@@ -604,9 +602,7 @@ class ShardedSweepPlan:
                                 in_specs=(P(), batch, batch, P()),
                                 out_specs=(P(), P(), batch, ext_specs),
                                 check_vma=False))
-        with obs.span("engine/sweep", lane="sharded", shards=self.n_shards,
-                      extensions=",".join(sorted(self.plan.names))):
-            loss_val, grads, logits, ext = fn(params, inputs, targets, rng)
+        loss_val, grads, logits, ext = fn(params, inputs, targets, rng)
         return Results(loss=loss_val, grads=grads, logits=logits, ext=ext)
 
     def accumulate(self, num_microbatches: int) -> "AccumulatedSweepPlan":
@@ -828,11 +824,7 @@ def _run_accumulated(model, params, inputs, targets, loss, extensions,
             model.kfra_apply(params, gbar, parts, extensions, cfg)[1],
             "kfra")
     for nm in carry_names:
-        # spans here record at trace time when this driver runs under jit
-        # or inside a shard_map body — still useful: finalize cost is
-        # dominated by tracing/lowering for the kron/KFRA replays.
-        with obs.span("engine/finalize", ext=nm, reducer=red[nm].name):
-            ext[nm] = red[nm].finalize(c_ext[nm], meta_fin)
+        ext[nm] = red[nm].finalize(c_ext[nm], meta_fin)
     ext.update(cat_ext)
     for nm in pair_names:
         ext[nm] = jax.tree.map(
@@ -922,12 +914,9 @@ class AccumulatedSweepPlan:
             cfg2 = dataclasses.replace(
                 cfg, shard_axes=None, total_units=mg, total_batch=n,
                 accum_stats=True, cross_split=None)
-            with obs.span("engine/sweep", lane="accumulated",
-                          k=self.num_microbatches, n=n,
-                          extensions=",".join(sorted(self.plan.names))):
-                lv, grads, logits, ext = _run_accumulated(
-                    model, params, inputs, targets, loss, extensions, cfg2,
-                    rng, self.num_microbatches)
+            lv, grads, logits, ext = _run_accumulated(
+                model, params, inputs, targets, loss, extensions, cfg2,
+                rng, self.num_microbatches)
             return Results(loss=lv, grads=grads, logits=logits, ext=ext)
 
         sp = self.sharded
@@ -959,11 +948,8 @@ class AccumulatedSweepPlan:
                                 in_specs=(P(), batch, batch, P(), P()),
                                 out_specs=(P(), P(), batch, ext_specs),
                                 check_vma=False))
-        with obs.span("engine/sweep", lane="shard_accumulate",
-                      k=k, n=n, shards=sp.n_shards,
-                      extensions=",".join(sorted(self.plan.names))):
-            lv, grads, logits, ext = fn(params, inputs, targets, rng,
-                                        jnp.asarray(mg, jnp.float32))
+        lv, grads, logits, ext = fn(params, inputs, targets, rng,
+                                    jnp.asarray(mg, jnp.float32))
         return Results(loss=lv, grads=grads, logits=logits, ext=ext)
 
     # -- preemption-safe streaming (SweepStream) ----------------------------

@@ -1099,6 +1099,12 @@ class Activation(Module):
 # ---------------------------------------------------------------------------
 
 
+def _rule(mod: Module):
+    """Named scope ``rule.<Module class>`` for a child's rule: its ops carry
+    the layer type in their ``op_name``, nested inside the sweep scopes."""
+    return jax.named_scope(f"rule.{type(mod).__name__}")
+
+
 class Sequential(Module):
     def __init__(self, mods: Sequence[Module]):
         self.mods = list(mods)
@@ -1118,39 +1124,44 @@ class Sequential(Module):
     def forward_tape(self, params, x):
         tapes = []
         for m, p in zip(self.mods, params):
-            x, t = m.forward_tape(p, x)
+            with _rule(m):
+                x, t = m.forward_tape(p, x)
             tapes.append(t)
         return x, tuple(tapes)
 
     def backward(self, params, tape, g, exts, cfg):
         grads, stats = [None] * len(self.mods), [None] * len(self.mods)
         for i in reversed(range(len(self.mods))):
-            g, grads[i], stats[i] = self.mods[i].backward(
-                params[i], tape[i], g, exts, cfg
-            )
+            with _rule(self.mods[i]):
+                g, grads[i], stats[i] = self.mods[i].backward(
+                    params[i], tape[i], g, exts, cfg
+                )
             if g is None and i > 0:
                 raise ValueError("cotangent vanished mid-chain")
         return g, tuple(grads), tuple(stats)
 
     def jac_t_mat(self, params, tape, M):
         for i in reversed(range(len(self.mods))):
-            M = self.mods[i].jac_t_mat(params[i], tape[i], M)
+            with _rule(self.mods[i]):
+                M = self.mods[i].jac_t_mat(params[i], tape[i], M)
         return M
 
     def curv_backward(self, params, tape, S, exts, cfg, ext_prefix):
         curv = [None] * len(self.mods)
         for i in reversed(range(len(self.mods))):
-            S, curv[i] = self.mods[i].curv_backward(
-                params[i], tape[i], S, exts, cfg, ext_prefix
-            )
+            with _rule(self.mods[i]):
+                S, curv[i] = self.mods[i].curv_backward(
+                    params[i], tape[i], S, exts, cfg, ext_prefix
+                )
         return S, tuple(curv)
 
     def kfra_backward(self, params, tape, Gbar, exts, cfg):
         stats = [None] * len(self.mods)
         for i in reversed(range(len(self.mods))):
-            Gbar, stats[i] = self.mods[i].kfra_backward(
-                params[i], tape[i], Gbar, exts, cfg
-            )
+            with _rule(self.mods[i]):
+                Gbar, stats[i] = self.mods[i].kfra_backward(
+                    params[i], tape[i], Gbar, exts, cfg
+                )
         return Gbar, tuple(stats)
 
     def kfra_partials(self, params, tape, cfg):
@@ -1208,7 +1219,8 @@ class Parallel(Module):
     def forward_tape(self, params, x):
         outs, tapes = [], []
         for m, p in zip(self.mods, params):
-            o, t = m.forward_tape(p, x)
+            with _rule(m):
+                o, t = m.forward_tape(p, x)
             outs.append(o)
             tapes.append(t)
         return tuple(outs), tuple(tapes)
@@ -1217,7 +1229,8 @@ class Parallel(Module):
         g_in = None
         grads, stats = [], []
         for m, p, t, gi in zip(self.mods, params, tape, g):
-            gx, gr, st = m.backward(p, t, gi, exts, cfg)
+            with _rule(m):
+                gx, gr, st = m.backward(p, t, gi, exts, cfg)
             grads.append(gr)
             stats.append(st)
             g_in = gx if g_in is None else jax.tree.map(jnp.add, g_in, gx)
@@ -1226,7 +1239,8 @@ class Parallel(Module):
     def jac_t_mat(self, params, tape, M):
         out = None
         for m, p, t, Mi in zip(self.mods, params, tape, M):
-            r = m.jac_t_mat(p, t, Mi)
+            with _rule(m):
+                r = m.jac_t_mat(p, t, Mi)
             out = r if out is None else jax.tree.map(jnp.add, out, r)
         return out
 
@@ -1234,7 +1248,8 @@ class Parallel(Module):
         out = None
         curv = []
         for m, p, t, Si in zip(self.mods, params, tape, S):
-            r, cv = m.curv_backward(p, t, Si, exts, cfg, ext_prefix)
+            with _rule(m):
+                r, cv = m.curv_backward(p, t, Si, exts, cfg, ext_prefix)
             curv.append(cv)
             out = r if out is None else jax.tree.map(jnp.add, out, r)
         return out, tuple(curv)
@@ -1272,18 +1287,24 @@ class Residual(Module):
         return x + self.inner.apply(params, x)
 
     def forward_tape(self, params, x):
-        y, t = self.inner.forward_tape(params, x)
+        with _rule(self.inner):
+            y, t = self.inner.forward_tape(params, x)
         return x + y, t
 
     def backward(self, params, tape, g, exts, cfg):
-        gx, grads, stats = self.inner.backward(params, tape, g, exts, cfg)
+        with _rule(self.inner):
+            gx, grads, stats = self.inner.backward(params, tape, g, exts, cfg)
         return g + gx, grads, stats
 
     def jac_t_mat(self, params, tape, M):
-        return M + self.inner.jac_t_mat(params, tape, M)
+        with _rule(self.inner):
+            M_in = self.inner.jac_t_mat(params, tape, M)
+        return M + M_in
 
     def curv_backward(self, params, tape, S, exts, cfg, ext_prefix):
-        S_in, curv = self.inner.curv_backward(params, tape, S, exts, cfg, ext_prefix)
+        with _rule(self.inner):
+            S_in, curv = self.inner.curv_backward(params, tape, S, exts, cfg,
+                                                  ext_prefix)
         return S + S_in, curv
 
     def decode_step(self, params, x, cache):
@@ -1361,7 +1382,8 @@ class ScanStack(Module):
 
     def forward_tape(self, params, x):
         def body(z, p):
-            z2, t = self.block.forward_tape(p, z)
+            with _rule(self.block):
+                z2, t = self.block.forward_tape(p, z)
             return self._constrain(z2), t
 
         with jax.named_scope(f"scanstack_T{self.L}"):
@@ -1371,7 +1393,8 @@ class ScanStack(Module):
     def backward(self, params, tape, g, exts, cfg):
         def body(gc, pt):
             p, t = pt
-            g_in, grads, stats = self.block.backward(p, t, gc, exts, cfg)
+            with _rule(self.block):
+                g_in, grads, stats = self.block.backward(p, t, gc, exts, cfg)
             return g_in, (grads, stats)
 
         with jax.named_scope(f"scanstack_T{self.L}"):
@@ -1382,7 +1405,8 @@ class ScanStack(Module):
     def jac_t_mat(self, params, tape, M):
         def body(Mc, pt):
             p, t = pt
-            return self.block.jac_t_mat(p, t, Mc), None
+            with _rule(self.block):
+                return self.block.jac_t_mat(p, t, Mc), None
 
         with jax.named_scope(f"scanstack_T{self.L}"):
             M_in, _ = jax.lax.scan(body, M, (params, tape), reverse=True)
@@ -1391,7 +1415,9 @@ class ScanStack(Module):
     def curv_backward(self, params, tape, S, exts, cfg, ext_prefix):
         def body(Sc, pt):
             p, t = pt
-            S_in, curv = self.block.curv_backward(p, t, Sc, exts, cfg, ext_prefix)
+            with _rule(self.block):
+                S_in, curv = self.block.curv_backward(p, t, Sc, exts, cfg,
+                                                      ext_prefix)
             return S_in, curv
 
         with jax.named_scope(f"scanstack_T{self.L}"):

@@ -158,7 +158,8 @@ def _interpret() -> bool:
 
 
 def dispatch(name: str, *args, **static) -> Any:
-    """Run a registered kernel through the jit cache.
+    """Run a registered kernel through the jit cache, under the named
+    scope ``kernel.<name>``.
 
     One jitted callable per (kernel, static opts, backend) config;
     per-shape compilation caching is jax.jit's own.
@@ -167,37 +168,33 @@ def dispatch(name: str, *args, **static) -> Any:
     interpret = _interpret()
     key = (name, tuple(sorted(static.items())), interpret)
     fn = _JIT_CACHE.get(key)
-    reg = obs.get()
     if fn is None:
         fn = jax.jit(partial(spec.wrapper, interpret=interpret, **static))
         _JIT_CACHE[key] = fn
         _CACHE_MISSES[name] = _CACHE_MISSES.get(name, 0) + 1
-        if reg.enabled:
-            reg.count(f"kernel.cache_miss.{name}")
     else:
         _CACHE_HITS[name] = _CACHE_HITS.get(name, 0) + 1
-        if reg.enabled:
-            reg.count(f"kernel.cache_hit.{name}")
     shapes = tuple(
         (tuple(a.shape), str(a.dtype)) for a in args if hasattr(a, "shape")
     )
     waste = _PAD_WASTE.get((key, shapes))
-    if waste is None:
-        # first time this config sees these shapes: the wrapper is about
-        # to trace (jax.jit's shape cache is cold), so _pad_to calls run
-        # now — collect their waste into a fresh accumulation cell
-        _PAD_NOTE.append([0])
-        try:
+    # the kernel's pads, casts, slices and its Pallas call carry its name
+    # in their op_name, inside whatever scopes the caller has open
+    with jax.named_scope(f"kernel.{name}"):
+        if waste is None:
+            # first time this config sees these shapes: the wrapper is
+            # about to trace (jax.jit's shape cache is cold), so _pad_to
+            # calls run now — collect their waste into a fresh cell
+            _PAD_NOTE.append([0])
+            try:
+                out = fn(*args)
+            finally:
+                waste = _PAD_NOTE.pop()[0]
+            _PAD_WASTE[(key, shapes)] = waste
+        else:
             out = fn(*args)
-        finally:
-            waste = _PAD_NOTE.pop()[0]
-        _PAD_WASTE[(key, shapes)] = waste
-    else:
-        out = fn(*args)
-    if reg.enabled:
-        reg.count(f"kernel.calls.{name}")
-        if waste:
-            reg.count(f"kernel.padding_waste_bytes.{name}", waste)
+    if waste:
+        obs.count(f"kernel.padding_waste_bytes.{name}", waste)
     return out
 
 

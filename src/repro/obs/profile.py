@@ -2,9 +2,10 @@
 
 ``obs.profile(dir)`` wraps ``jax.profiler.start_trace`` / ``stop_trace``
 as a context manager (no-op when ``dir`` is falsy), so a device trace can
-be captured around any region — the sweep drivers' ``jax.named_scope``
-annotations (``accumscan_T{k}``, ``gramscan_T{n}``, per-sweep scopes in
-``engine.run``) make the device timeline line up with obs spans.
+be captured around any region.  Device ops carry the program's
+``jax.named_scope`` names (the sweeps, ``rule.<Module>``,
+``kernel.<name>``); recording obs spans are profiler TraceMes, so they
+land on the Python thread's line of the same trace, on the same clock.
 """
 from __future__ import annotations
 

@@ -6,11 +6,20 @@ primitives cover every instrumentation site:
 * ``span(name, **attrs)`` — a context-manager wall-clock timer on
   ``time.perf_counter``.  Spans nest: each records its *path* (the stack of
   enclosing span names), so ``report()`` can render the measured tree the
-  same way ``describe()`` renders the planned one.
-* ``count(name, value=1)`` — a monotonic counter (kernel invocations,
-  cache hits, restarts, padding-waste bytes).
+  same way ``describe()`` renders the planned one.  A recording span is
+  also a ``jax.profiler.TraceAnnotation``: under ``obs.profile`` it lands
+  on the Python thread's line of the device trace, on the device ops'
+  clock.
+* ``count(name, value=1)`` — a monotonic counter (compiles, restarts,
+  padding-waste bytes).
 * ``gauge(name, value)`` — a last-value-wins sample (stream cursor,
   tokens/s).
+
+Compiles are recorded too: the first recording registry registers one
+process-wide ``jax.monitoring`` listener that turns every XLA backend
+compile into the counters ``jax.compiles`` and ``jax.compile_s`` and a
+``compile`` event carrying the enclosing span path, so a report says which
+span recompiled.
 
 Two sinks: the in-memory event list (always on when enabled) and an
 optional JSONL file, one event per line, written as events occur so a
@@ -21,8 +30,8 @@ Off-by-default-cheap: the module-level registry starts as
 manager and whose recorders are ``pass`` — a disabled call site costs one
 attribute lookup and one no-op call, and allocates nothing.
 
-jit-safety contract: instrumentation records on the *host*, at dispatch or
-trace time.  Nothing here may be called with tracers as attr values —
+jit-safety contract: instrumentation records on the *host*, never from
+compiled code.  Nothing here may be called with tracers as attr values —
 ``_clean`` coerces non-JSON scalars via ``str`` so a stray tracer can
 never poison a sink, but hot paths are expected to pass static Python
 scalars only.
@@ -123,9 +132,10 @@ class Span:
     """A live span.  Created by :meth:`ObsRegistry.span`; use as a context
     manager.  ``set(**attrs)`` attaches attrs any time before exit (e.g. a
     byte count known only mid-body).  After exit, ``dur_s`` holds the
-    measured duration."""
+    measured duration.  While open it is also a profiler ``TraceMe`` of
+    the same name (about a microsecond when no profile is being taken)."""
 
-    __slots__ = ("_reg", "name", "attrs", "path", "t0", "dur_s")
+    __slots__ = ("_reg", "name", "attrs", "path", "t0", "dur_s", "_tm")
 
     def __init__(self, reg: "ObsRegistry", name: str, attrs: Dict[str, Any]):
         self._reg = reg
@@ -134,6 +144,7 @@ class Span:
         self.path: Tuple[str, ...] = ()
         self.t0 = 0.0
         self.dur_s = 0.0
+        self._tm = None
 
     def set(self, **attrs: Any) -> "Span":
         self.attrs.update(attrs)
@@ -143,11 +154,14 @@ class Span:
         stack = self._reg._stack
         self.path = (stack[-1].path if stack else ()) + (self.name,)
         stack.append(self)
+        self._tm = _TraceMe(self.name)
+        self._tm.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
         end = time.perf_counter()
+        self._tm.__exit__(None, None, None)
         self.dur_s = end - self.t0
         stack = self._reg._stack
         if stack and stack[-1] is self:
@@ -179,6 +193,7 @@ class ObsRegistry:
         self._sink: Optional[IO[str]] = (
             open(trace_jsonl, "w") if trace_jsonl else None
         )
+        _hook_jax()
 
     # -- recording ---------------------------------------------------------
     def span(self, name: str, /, **attrs: Any) -> Span:
@@ -192,6 +207,14 @@ class ObsRegistry:
         value = _clean(value)
         self.gauges[name] = value
         self._emit({"kind": "gauge", "name": name, "value": value})
+
+    def compiled(self, fun_name: str, dur_s: float) -> None:
+        """One XLA backend compile of ``fun_name``, inside the open spans."""
+        self.count("jax.compiles")
+        self.count("jax.compile_s", dur_s)
+        path = list(self._stack[-1].path) if self._stack else []
+        self._emit({"kind": "compile", "name": fun_name, "path": path,
+                    "dur_s": round(dur_s, 9)})
 
     def _record_span(self, sp: Span, end: float) -> None:
         self._emit(
@@ -234,6 +257,28 @@ class ObsRegistry:
 
 # -- module-level current registry ----------------------------------------
 _REGISTRY: Any = NullRegistry()
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_TraceMe: Any = None  # jax.profiler.TraceAnnotation, bound by _hook_jax
+
+
+def _on_duration(event: str, duration_secs: float, **kwargs: Any) -> None:
+    if event == _BACKEND_COMPILE and _REGISTRY.enabled:
+        _REGISTRY.compiled(str(kwargs.get("fun_name", "?")), duration_secs)
+
+
+def _hook_jax() -> None:
+    """Bind the profiler annotation and register the compile listener,
+    once per process (deferred so that importing repro.obs stays light).
+    The listener forwards to whichever registry is current and does
+    nothing under NullRegistry."""
+    global _TraceMe
+    if _TraceMe is None:
+        import jax.monitoring
+        import jax.profiler
+
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _TraceMe = jax.profiler.TraceAnnotation
 
 
 def get() -> Any:

@@ -5,7 +5,8 @@ the in-memory event list, and ``tools/obs_report.py`` feeds it a JSONL
 trace loaded with ``load_jsonl``.  Spans aggregate by their *path* (the
 nesting stack of span names), so the output mirrors ``describe()``'s plan
 tree — but with measured wall time, call counts, and summed numeric attrs
-(bytes, rows) instead of the planned schedule.
+(bytes, rows) instead of the planned schedule.  Compile events aggregate
+by the span path they happened under and the compiled function.
 """
 from __future__ import annotations
 
@@ -51,6 +52,8 @@ def render(events: List[Dict[str, Any]]) -> str:
     order: List[Tuple[str, ...]] = []  # first-seen order, parents first
     counters: Dict[str, float] = {}
     gauges: Dict[str, float] = {}
+    # (span path, compiled function) -> [compiles, total_s]
+    compiles: Dict[Tuple[str, str], List[Any]] = {}
     for ev in events:
         kind = ev.get("kind")
         if kind == "span":
@@ -73,6 +76,11 @@ def render(events: List[Dict[str, Any]]) -> str:
             counters[ev["name"]] = counters.get(ev["name"], 0) + ev.get("value", 1)
         elif kind == "gauge":
             gauges[ev["name"]] = ev.get("value")
+        elif kind == "compile":
+            where = "/".join(ev.get("path") or []) or "(no span)"
+            row = compiles.setdefault((where, ev.get("name", "?")), [0, 0.0])
+            row[0] += 1
+            row[1] += float(ev.get("dur_s", 0.0))
 
     lines: List[str] = []
     if agg:
@@ -103,6 +111,11 @@ def render(events: List[Dict[str, Any]]) -> str:
                 f"{k}={attrs[k]:g}" for k in sorted(attrs)
             )
             lines.append(row + ("  " + extras if extras else ""))
+    if compiles:
+        lines.append("")
+        lines.append("compiles (under span path)")
+        for (where, fn), (n, total) in sorted(compiles.items()):
+            lines.append(f"  {where}: {fn}  x{n}  {_fmt_s(total)}")
     if counters:
         lines.append("")
         lines.append("counters")

@@ -105,22 +105,23 @@ def fit(model, cfg, shape, opt, loop: LoopConfig,
     for step in range(start_step, loop.steps):
         if injector is not None:
             injector.check(step)
-        batch = batch_for(cfg, shape, step, seed=loop.seed,
-                          batch=loop.batch_override)
-        # perf_counter is the one wall clock for durations (monotonic on
-        # every platform, highest resolution) — the obs span uses it too
-        t0 = time.perf_counter()
         with obs.span("train/step", step=step):
+            with obs.span("train/data"):
+                batch = batch_for(cfg, shape, step, seed=loop.seed,
+                                  batch=loop.batch_override)
+            # perf_counter is the one wall clock for durations (monotonic
+            # on every platform, highest resolution) — obs spans use it too
+            t0 = time.perf_counter()
+            args = (params, opt_state, batch, jnp.int32(step))
             if extensions or prebuilt:
-                rng = jax.random.fold_in(jax.random.PRNGKey(loop.seed + 1),
-                                         step)
-                params, opt_state, metrics = step_fn(
-                    params, opt_state, batch, jnp.int32(step), rng)
-            else:
-                params, opt_state, metrics = step_fn(
-                    params, opt_state, batch, jnp.int32(step))
-            metrics = {k: float(v) for k, v in metrics.items()}
-        dur = time.perf_counter() - t0
+                args += (jax.random.fold_in(
+                    jax.random.PRNGKey(loop.seed + 1), step),)
+            params, opt_state, metrics = run_step(step_fn, *args)
+            dur = time.perf_counter() - t0
+        if step == start_step:
+            # output buffers a step, which dispatch time grows with
+            obs.gauge("train.step_outputs",
+                      len(jax.tree.leaves((params, opt_state, metrics))))
         stalled = wd.stalled()  # gap since the previous beat, pre-beat
         ok = wd.beat(step, dur)
         # per-step duration + watchdog state ride the history so post-hoc
@@ -146,6 +147,22 @@ def fit(model, cfg, shape, opt, loop: LoopConfig,
         ckpt.save(loop.ckpt_dir, loop.steps, params, opt_state,
                   keep=loop.ckpt_keep)
     return params, opt_state, history, wd
+
+
+def run_step(step_fn: Callable, *args):
+    """One training step: ``step_fn(*args)`` returns ``(params, opt_state,
+    metrics)``; returns them with the metrics as host floats.
+
+    The call, until the jitted function returns, is the span
+    ``train/dispatch``; the wait for the metrics and their one
+    device→host read is ``train/sync``.  A jitted step writes every output
+    buffer when its program ends, so the metrics are ready when the whole
+    step is."""
+    with obs.span("train/dispatch"):
+        params, opt_state, metrics = step_fn(*args)
+    with obs.span("train/sync"):
+        metrics = {k: float(v) for k, v in jax.device_get(metrics).items()}
+    return params, opt_state, metrics
 
 
 def fit_with_restarts(model, cfg, shape, opt, loop: LoopConfig,

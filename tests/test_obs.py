@@ -1,8 +1,11 @@
 """Observability layer: span nesting, JSONL round-trip, disabled no-op
-identity, jit-safety of kernel-dispatch telemetry, cache hit/miss counters,
-padding-waste accounting, and the streaming-sweep trace acceptance check
-(per-slice span count matches the described schedule; the offline renderer
-agrees with the in-memory report)."""
+identity, compile events, the cache hit/miss stats, padding-waste
+accounting, the trainer's step spans on the profiler's clock, and the
+streaming-sweep trace acceptance check (per-slice span count matches the
+described schedule; the offline renderer agrees with the in-memory
+report)."""
+import dataclasses
+import glob
 import json
 import os
 import subprocess
@@ -161,21 +164,29 @@ def test_disabled_and_enabled_sweeps_agree():
 # ---------------------------------------------------------------------------
 
 
+def _compiles(reg):
+    return [e for e in reg.events if e["kind"] == "compile"]
+
+
 def test_cache_hit_miss_counters():
+    """The dispatch cache's hits and misses live in ``cache_stats()``; the
+    registry sees the one compile the miss causes, and none on the hit."""
     ops.clear_cache()
     A = jnp.ones((4, 5, 3))
     B = jnp.ones((4, 5, 2))
     reg = ObsRegistry()
     with obs.use(reg):
         ops.batch_l2(A, B)
+        first = len(_compiles(reg))
         ops.batch_l2(A, B)
     stats = ops.cache_stats()
     assert stats["misses"]["batch_l2"] == 1
     assert stats["hits"]["batch_l2"] == 1
     assert isinstance(stats["total"], int)  # legacy shape preserved
-    assert reg.counters["kernel.cache_miss.batch_l2"] == 1
-    assert reg.counters["kernel.cache_hit.batch_l2"] == 1
-    assert reg.counters["kernel.calls.batch_l2"] == 2
+    assert first >= 1 and len(_compiles(reg)) == first
+    assert reg.counters["jax.compiles"] == first
+    assert not any(k.startswith(("kernel.calls", "kernel.cache_"))
+                   for k in reg.counters)
 
 
 def test_padding_waste_matches_hand_computed_bytes():
@@ -195,20 +206,25 @@ def test_padding_waste_matches_hand_computed_bytes():
 
 
 def test_dispatch_records_at_trace_time_not_per_eval():
-    """Inside jit, dispatch (and its obs counters) runs once at trace time;
-    steady-state calls of the jitted wrapper must not grow the counters."""
+    """Inside jit, dispatch runs once at trace time and the program compiles
+    once: steady-state calls of the jitted wrapper fire no compile event."""
     ops.clear_cache()
     A = jnp.ones((4, 5, 3))
     B = jnp.ones((4, 5, 2))
     fn = jax.jit(lambda A, B: ops.batch_l2(A, B))
     reg = ObsRegistry()
     with obs.use(reg):
-        for _ in range(3):
+        jax.block_until_ready(fn(A, B))
+        traced = len(_compiles(reg))
+        for _ in range(2):
             jax.block_until_ready(fn(A, B))
-    assert reg.counters["kernel.calls.batch_l2"] == 1  # the trace, only
+    assert traced >= 1 and len(_compiles(reg)) == traced  # cached calls
+    assert ops.cache_stats()["misses"]["batch_l2"] == 1
     with obs.use(reg):
-        ops.batch_l2(A, B)  # eager: dispatch really runs
-    assert reg.counters["kernel.calls.batch_l2"] == 2
+        ops.batch_l2(A, B)  # eager: the kernel's own jit compiles once
+        eager = len(_compiles(reg))
+        ops.batch_l2(A, B)
+    assert eager > traced and len(_compiles(reg)) == eager
 
 
 # ---------------------------------------------------------------------------
@@ -276,3 +292,131 @@ def test_report_renders_tree_counters_gauges():
     assert "step=" not in a_line  # identifiers are not summed
     assert "calls = 3" in out and "cursor = 5" in out
     assert render([]) == "no events recorded"
+
+
+# ---------------------------------------------------------------------------
+# compile events and the trainer's step spans
+# ---------------------------------------------------------------------------
+
+
+def test_compile_events_count_backend_compiles_under_span():
+    """A jitted function called under a span compiles once: ``jax.compiles``
+    rises by the backend compiles of the first call and by 0 on the
+    second, and each ``compile`` event carries the span's path."""
+    fn = jax.jit(lambda x: jnp.sin(x) * 3.0 + 1.0)
+    x = jnp.arange(7.0)
+    reg = ObsRegistry()
+    with obs.use(reg):
+        with obs.span("outer"), obs.span("call", k=1):
+            jax.block_until_ready(fn(x))
+        first = reg.counters.get("jax.compiles", 0)
+        with obs.span("outer"), obs.span("call", k=2):
+            jax.block_until_ready(fn(x))
+    events = _compiles(reg)
+    assert first >= 1 and reg.counters["jax.compiles"] == first
+    assert len(events) == first
+    assert all(e["path"] == ["outer", "call"] for e in events)
+    assert any("<lambda>" in e["name"] for e in events)
+    assert reg.counters["jax.compile_s"] == pytest.approx(
+        sum(e["dur_s"] for e in events), rel=1e-6)
+    report = render(reg.events)
+    assert "compiles (under span path)" in report
+    assert "outer/call: " in report
+
+
+def test_compile_listener_is_silent_when_disabled(monkeypatch):
+    """Compiles after the switch back to NullRegistry reach no registry:
+    the recording registry's count stays where it was, and no registry's
+    ``compiled`` is called."""
+    reg = ObsRegistry()  # registers the process-wide listener
+    with obs.use(reg):
+        jax.block_until_ready(jax.jit(lambda x: x * 5.0 - 2.0)(jnp.ones(3)))
+    before = reg.counters["jax.compiles"]
+    assert before >= 1 and isinstance(obs.get(), NullRegistry)
+    calls = []
+    monkeypatch.setattr(ObsRegistry, "compiled",
+                        lambda self, *a: calls.append(a))
+    jax.block_until_ready(jax.jit(lambda x: x * 7.0 + 4.0)(jnp.ones(5)))
+    assert calls == []
+    assert reg.counters["jax.compiles"] == before
+
+
+def test_run_step_syncs_reads_metrics_and_counts_outputs():
+    """``run_step`` times dispatch and sync and reads the metrics as host
+    floats; ``fit`` sets the output-buffer gauge once, on its first step."""
+    from repro.configs import ARCHS, SHAPES
+    from repro.nn.models import build_model
+    from repro.optim import adamw
+    from repro.train.loop import LoopConfig, fit, run_step
+
+    def step(p, s, x):
+        return p + x, {"n": s["n"] + 1}, {"loss": jnp.sum(x), "acc": x[0]}
+
+    reg = ObsRegistry()
+    with obs.use(reg):
+        p, s, metrics = run_step(jax.jit(step), jnp.ones(3), {"n": 0},
+                                 jnp.arange(3.0))
+    assert metrics == {"loss": 3.0, "acc": 0.0}
+    assert all(type(v) is float for v in metrics.values())
+    np.testing.assert_array_equal(p, [1.0, 2.0, 3.0])
+    assert int(s["n"]) == 1
+    assert [e["name"] for e in reg.events if e["kind"] == "span"] == \
+        ["train/dispatch", "train/sync"]
+    assert reg.gauges == {}
+
+    cfg = ARCHS["stablelm-1.6b"].reduced()
+    shape = dataclasses.replace(SHAPES["train_4k"], seq_len=8,
+                                global_batch=2)
+    reg = ObsRegistry()
+    with obs.use(reg):
+        params, opt_state, _, _ = fit(build_model(cfg), cfg, shape,
+                                      adamw(1e-3),
+                                      LoopConfig(steps=2, log_every=1000))
+    n_out = len(jax.tree.leaves((params, opt_state))) + 2  # loss, step
+    assert reg.gauges["train.step_outputs"] == n_out
+    assert [e["name"] for e in reg.events if e["kind"] == "gauge"] == \
+        ["train.step_outputs"]
+
+
+def _fit_python_line(tmp_path, enable):
+    """Two ``fit`` steps of a small LM under ``obs.profile``; the events
+    of the xplane's Python-thread line as ``(name, start, end)``."""
+    from jax.profiler import ProfileData
+
+    from repro.configs import ARCHS, SHAPES
+    from repro.nn.models import build_model
+    from repro.optim import adamw
+    from repro.train.loop import LoopConfig, fit
+
+    cfg = ARCHS["stablelm-1.6b"].reduced()
+    shape = dataclasses.replace(SHAPES["train_4k"], seq_len=8,
+                                global_batch=2)
+    if enable:
+        obs.enable()
+    with obs.profile(str(tmp_path)):
+        fit(build_model(cfg), cfg, shape, adamw(1e-3),
+            LoopConfig(steps=2, log_every=1000))
+    obs.disable()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = next(p for p in ProfileData.from_file(path).planes
+                if p.name == "/host:CPU")
+    line = next(ln for ln in host.lines if ln.name.startswith("python"))
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for e in line.events]
+
+
+def test_fit_step_spans_on_the_profiler_clock(tmp_path):
+    events = _fit_python_line(tmp_path, enable=True)
+    steps = sorted(e for e in events if e[0] == "train/step")
+    assert len(steps) == 2
+    for name in ("train/data", "train/dispatch", "train/sync"):
+        inner = sorted(e for e in events if e[0] == name)
+        assert len(inner) == 2, name
+        for (_, s, t), (_, s0, t0) in zip(inner, steps):
+            assert s0 <= s <= t <= t0, name
+
+
+def test_fit_step_spans_absent_when_disabled(tmp_path):
+    names = {e[0] for e in _fit_python_line(tmp_path, enable=False)}
+    assert not names & {"train/step", "train/data", "train/dispatch",
+                        "train/sync"}

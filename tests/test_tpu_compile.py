@@ -13,12 +13,22 @@ layers at the paper's batch N=128 (CIFAR-10, 32x32, unfold widths
 75/576/864) and its dense layers, and stablelm-1.6b's projections and
 100,352-wide head at 8 x 256 tokens.
 
+The last two tests compile the whole tiny 3C3D extended step of the chip
+benchmark (``perfbench/tests/tiny.py``, kernels on) and read the
+program's own ``rule.*`` and ``kernel.*`` scopes in its HLO, beside the
+scope and label the benchmark's trace reduction
+(``perfbench/harness/trace.py``) gives each instruction.
+
 The topology is described inside a module fixture, never at import or
 collection time: only one process may hold the TPU library at a time.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
 import os
+import re
+import sys
 from functools import partial
 
 import jax
@@ -130,3 +140,99 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache):
     compiled = jax.jit(partial(wrapper, interpret=False, **static)).lower(
         *args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- the tiny 3C3D extended step of the chip benchmark, whole ------------------
+
+PERFBENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "perfbench")
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*op_name=\"([^\"]*)\"")
+_TAG = re.compile(r"\b(?:rule|kernel)\.[A-Za-z0-9_]+")
+
+
+def _scope_tags(hlo_text):
+    """``{instruction: (tag, ...)}``: the innermost ``rule.*`` and the
+    innermost ``kernel.*`` component of each instruction's ``op_name``."""
+    out = {}
+    for line in hlo_text.splitlines():
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        found = _TAG.findall(m.group(2))
+        tags = tuple(t for t in (
+            next((t for t in reversed(found) if t.startswith(k)), None)
+            for k in ("rule.", "kernel.")) if t)
+        if tags:
+            out[m.group(1)] = tags
+    return out
+
+
+@pytest.fixture(scope="module")
+def tiny_step_hlo(one_chip, no_persistent_cache, tmp_path_factory):
+    """Compiled HLO text of the tiny extended step, kernels on, with the
+    program's rule/kernel scopes (``"tagged"``) and without (``"bare"``)."""
+    import numpy as np
+
+    for d in (os.path.join(PERFBENCH, "tests"), PERFBENCH):
+        if d not in sys.path:
+            sys.path.insert(0, d)
+    import tiny
+    from harness import discovery
+
+    root = tiny.make_root(tmp_path_factory.mktemp("tags"), ["c3d3.small"])
+    cell = discovery.find_cell(root, "c3d3.small")
+    job, ref = cell.job(), cell.module("reference")
+    key = jax.random.PRNGKey(0)
+    p0 = ref.init_params(cell.config, key)
+    batch = ref.make_batches(cell.config, cell.traffic, key, 1)[0]
+    real_scope, interpret = jax.named_scope, ops._interpret
+
+    def bare_scope(name):
+        return (contextlib.nullcontext()
+                if name.startswith(("rule.", "kernel.")) else real_scope(name))
+
+    def compile_step(scope):
+        jax.named_scope = scope
+        try:
+            model = cell.module("build").build(cell.config)
+            step, _, opt, _ = job.program_steps(cell, model)
+            args = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                               sharding=one_chip),
+                (p0, opt.init(p0), batch, np.int32(0), key))
+            return step.lower(*args).compile().as_text()
+        finally:
+            jax.named_scope = real_scope
+
+    ops._interpret = lambda: False
+    try:
+        yield {"tagged": compile_step(real_scope),
+               "bare": compile_step(bare_scope)}
+    finally:
+        ops._interpret = interpret
+
+
+def test_every_pallas_op_lies_under_its_own_kernel_scope(tiny_step_hlo):
+    from harness import trace
+
+    index = trace.hlo_index(tiny_step_hlo["tagged"])
+    tags = _scope_tags(tiny_step_hlo["tagged"])
+    kernels = collections.Counter()
+    for name, (_, label) in index.items():
+        if label.startswith("pallas:"):
+            kernel = label.split(":", 1)[1]
+            assert "kernel." + kernel in tags.get(name, ()), (name, label)
+            kernels[kernel] += 1
+    assert {"cross_dot", "fused_first_order",
+            "fused_second_order"} <= set(kernels)
+    rules = {t for ts in tags.values() for t in ts if t.startswith("rule.")}
+    assert {"rule.Conv2d", "rule.Dense"} <= rules
+    assert _scope_tags(tiny_step_hlo["bare"]) == {}
+
+
+def test_scopes_leave_each_instruction_scope_and_label(tiny_step_hlo):
+    from harness import trace
+
+    tagged = trace.hlo_index(tiny_step_hlo["tagged"])
+    assert tagged == trace.hlo_index(tiny_step_hlo["bare"])
+    assert any(scope == "first_order_sweep" for scope, _ in tagged.values())
