@@ -186,7 +186,8 @@ class FusedMask:
     Maps 1:1 onto the fused kernel's outputs: ``l2`` ↔ BatchL2, ``moment`` ↔
     SecondMoment/Variance (both reduce the summed squared gradient).  An
     unset flag means that output is never allocated or computed inside the
-    kernel.  BatchDot's pairwise dots are the ``cross_dot`` kernel's.
+    kernel.  BatchDot's pairwise dots read BatchGrad's per-sample
+    gradients when both are requested, else the ``cross_dot`` kernel.
     """
 
     l2: bool = False
@@ -340,8 +341,9 @@ class ExtensionConfig:
     # is the concatenation of two microbatch slices, and pairwise stats
     # (batch_dot / ntk*) should emit ONLY the cross block rows[:cross_split]
     # × rows[cross_split:] — computed through the fused cross-block kernel
-    # (``kernels.ops.cross_dot``) when kernels are on.  Ignored under
-    # ``shard_axes`` (sharded pairwise stats compute full gathered-column
-    # rows; the driver slices the blocks).  Set by the accumulated
-    # driver's pair passes; never set this by hand.
+    # (``kernels.ops.cross_dot``) when kernels are on, or for batch_dot
+    # from BatchGrad's per-sample gradients when both are requested.
+    # Ignored under ``shard_axes`` (sharded pairwise stats compute full
+    # gathered-column rows; the driver slices the blocks).  Set by the
+    # accumulated driver's pair passes; never set this by hand.
     cross_split: Optional[int] = None

@@ -35,6 +35,8 @@ from typing import Any, Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 from .extensions import (
     ExtensionConfig,
     FusedMask,
@@ -196,9 +198,24 @@ def dense_first_order_stats(A, B, exts, cfg: ExtensionConfig, bias: bool):
     and the summed squared gradient come out of ONE fused Pallas launch
     over (A, B); the static :class:`~repro.core.extensions.FusedMask`
     selects the outputs.  With ``use_fused=False`` each statistic runs its
-    own legacy kernel (the benchmark baseline).  Pairwise dots run on the
-    ``cross_dot`` kernel either way.  Bias stats are cheap row-sums and
-    stay in jnp.
+    own legacy kernel (the benchmark baseline).  Bias stats are cheap
+    row-sums and stay in jnp.
+
+    BatchDot's weight leaf takes the first route that applies, counted
+    once per call under ``rule.batch_dot.<route>``:
+
+    * ``rank1`` (R == 1): the closed form (A₁A₂ᵀ) ∘ (B₁B₂ᵀ);
+    * ``from_batch_grad`` (BatchGrad requested, not batch-sharded): the
+      Gram rows G·Gᵀ of the per-sample gradients BatchGrad already formed
+      (its ``[:cs] × [cs:]`` block under ``cross_split``), on either
+      ``use_kernels`` route -- no per-sample gradient is formed twice;
+    * ``cross_dot`` (``use_kernels``): the Pallas pair-grid kernel on the
+      factors;
+    * ``einsum``: :func:`per_sample_dots` on the factors.
+
+    A batch-sharded sweep keeps the factor routes even with BatchGrad in
+    hand: it gathers the [N, R, a+b] factors, not [N, a·b] gradients, and
+    which is smaller depends on the layer's shape.
     """
     names = {e.name for e in exts}
     mask = first_order_mask(names)
@@ -244,14 +261,24 @@ def dense_first_order_stats(A, B, exts, cfg: ExtensionConfig, bias: bool):
         B1, B2 = _pair_operands(Bf, axes, cross)
         if rank1:
             # (A1 A2ᵀ) ∘ (B1 B2ᵀ), O(N·M·(a+b)).
+            route = "rank1"
             dw = (A1[:, 0] @ A2[:, 0].T) * (B1[:, 0] @ B2[:, 0].T)
+        elif "batch_grad" in out and not axes:
+            # G = A_nᵀB_n is in hand for BatchGrad: one G·Gᵀ, O(N·M·a·b).
+            route = "from_batch_grad"
+            dw = _pairwise_rows(out["batch_grad"]["w"], None, cross)
         elif cfg.use_kernels:
             from repro.kernels import ops as kops
 
+            # No G in hand (BatchDot alone, or batch-sharded): the pair grid.
+            route = "cross_dot"
             dw = kops.cross_dot(A1, B1, A2, B2)
         else:
-            # The einsum forms [N, M, R, R] (64 GiB at 3C3D conv1, N=128).
+            # No G in hand, kernels off: the einsum forms [N, M, R, R]
+            # (64 GiB at 3C3D conv1, N=128).
+            route = "einsum"
             dw = per_sample_dots(A1, B1, A2, B2)
+        obs.count(f"rule.batch_dot.{route}")
         if bias:
             bsum = jnp.sum(Bf, axis=1)
             out["batch_dot"] = {"w": dw,

@@ -113,3 +113,140 @@ def test_batch_dot_matches_oracle(setup):
         # diagonal of the pairwise dots == batch_l2
         np.testing.assert_allclose(np.asarray(jnp.diagonal(d)),
                                    np.asarray(l2), rtol=2e-4, atol=1e-8)
+
+
+# -- BatchDot's weight leaf from the per-sample gradients BatchGrad formed ----
+
+ROUTES = "rule.batch_dot."
+
+
+def _routes(fn):
+    """``(fn(), {route: count})`` of the ``rule.batch_dot.*`` counters."""
+    from repro import obs
+
+    reg = obs.ObsRegistry()
+    with obs.use(reg):
+        out = fn()
+    return out, {k[len(ROUTES):]: v for k, v in reg.counters.items()
+                 if k.startswith(ROUTES)}
+
+
+@pytest.fixture(scope="module")
+def conv_setup():
+    """2C2D on 8x8 images (both convolutions at R > 1), its per-sample
+    gradients, and each Conv2d's unfolded input A [N, R, a] with a random
+    output cotangent B [N, R, b] (already / N) for the rule called
+    directly."""
+    from repro.configs.papernets import c2d2
+    from repro.nn.layers import Conv2d
+
+    model = c2d2(n_classes=C, in_ch=1, img=8)
+    params = model.init(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (N, 8, 8, 1))
+    y = jax.random.randint(jax.random.PRNGKey(2), (N,), 0, C)
+    loss = CrossEntropyLoss()
+    psg = oracle.per_sample_grads(model, loss, params, x, y)
+    _, tapes = model.forward_tape(params, x)
+    factors = []
+    for i, (m, t) in enumerate(zip(model.mods, tapes)):
+        if isinstance(m, Conv2d):
+            pat, (hh, ww) = m._unfold(t)
+            B = jax.random.normal(jax.random.PRNGKey(10 + i),
+                                  (N, hh * ww, m.c_out)) / N
+            factors.append((pat, B))
+    return model, params, x, y, loss, psg, factors
+
+
+def _gram(g, cs=None):
+    g = np.asarray(g, np.float64).reshape(g.shape[0], -1)
+    return g @ g.T if cs is None else g[:cs] @ g[cs:].T
+
+
+@pytest.mark.parametrize("split", [None, 2], ids=["whole", "cross_split"])
+@pytest.mark.parametrize("use_kernels", [False, True],
+                         ids=["jnp", "kernels"])
+def test_batch_dot_reads_batch_grad(conv_setup, use_kernels, split):
+    """With BatchGrad requested, BatchDot's weight leaf at R > 1 is G·Gᵀ of
+    the returned per-sample gradients (the ``[:cs] × [cs:]`` block under
+    ``cross_split``), once per convolution, on either ``use_kernels``
+    route; without BatchGrad the factor route (``cross_dot`` kernel or
+    einsum) still serves it and agrees."""
+    from repro.core import BatchDot, ExtensionConfig
+    from repro.core.module import dense_first_order_stats
+    from repro.nn.layers import Conv2d
+
+    model, params, x, y, loss, psg, factors = conv_setup
+    n_conv, n_dense = len(factors), 2
+    factor_route = "cross_dot" if use_kernels else "einsum"
+    if split is None:
+        cfg = ExtensionConfig(use_kernels=use_kernels)
+        both, seen = _routes(lambda: run(model, params, x, y, loss,
+                                         extensions=(BatchGrad, BatchDot),
+                                         cfg=cfg))
+        alone, seen_alone = _routes(lambda: run(model, params, x, y, loss,
+                                                extensions=(BatchDot,),
+                                                cfg=cfg))
+        assert seen == {"from_batch_grad": n_conv, "rank1": n_dense}
+        assert seen_alone == {factor_route: n_conv, "rank1": n_dense}
+        pairs = [(both["batch_grad"][i]["w"], both["batch_dot"][i]["w"],
+                  alone["batch_dot"][i]["w"], psg[i]["w"])
+                 for i, m in enumerate(model.mods) if isinstance(m, Conv2d)]
+    else:
+        cfg = ExtensionConfig(use_kernels=use_kernels, cross_split=split)
+        pairs = []
+        for A, B in factors:
+            both, seen = _routes(lambda: dense_first_order_stats(
+                A, B, (BatchGrad, BatchDot), cfg, bias=True))
+            alone, seen_alone = _routes(lambda: dense_first_order_stats(
+                A, B, (BatchDot,), cfg, bias=True))
+            assert seen == {"from_batch_grad": 1}
+            assert seen_alone == {factor_route: 1}
+            want = np.einsum("nra,nrb->nab", np.asarray(A, np.float64),
+                             np.asarray(B, np.float64))
+            pairs.append((both["batch_grad"]["w"], both["batch_dot"]["w"],
+                          alone["batch_dot"]["w"], want))
+    assert len(pairs) == n_conv
+    for g, dot, dot_alone, want in pairs:
+        assert dot.shape == ((N, N) if split is None else (split, N - split))
+        np.testing.assert_allclose(np.asarray(dot), _gram(g, split),
+                                   rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(dot), _gram(want, split),
+                                   rtol=2e-4, atol=1e-8)
+        np.testing.assert_allclose(np.asarray(dot_alone), np.asarray(dot),
+                                   rtol=2e-4, atol=1e-8)
+
+
+def test_batch_dot_routes_on_3c3d():
+    """The chip benchmark's 3C3D with its ``dot_mc`` traffic, traced at the
+    published 32x32: BatchDot reads BatchGrad's per-sample gradients at
+    the three convolutions and takes the rank-1 closed form at the three
+    dense layers; no layer runs ``cross_dot``."""
+    import importlib.util
+    import json
+    import os
+
+    from repro.core import ExtensionConfig, by_name
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "perfbench")
+    with open(os.path.join(bench, "configs", "c3d3", "config.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(bench, "traffic", "dot_mc.n128.json")) as f:
+        traffic = json.load(f)
+    spec = importlib.util.spec_from_file_location(
+        "c3d3_build", os.path.join(bench, "configs", "c3d3", "build.py"))
+    build = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(build)
+    model = build.build(config)
+    side, n = config["img"], 4
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    x = jax.ShapeDtypeStruct((n, side, side, config["in_channels"]),
+                             jnp.float32)
+    y = jax.ShapeDtypeStruct((n,), jnp.int32)
+    exts = tuple(by_name(e) for e in traffic["extensions"])
+    cfg = ExtensionConfig(**traffic["ext_config"])
+    _, seen = _routes(lambda: jax.eval_shape(
+        lambda p, x, y, k: run(model, p, x, y, CrossEntropyLoss(), exts,
+                               cfg, rng=k).ext,
+        params, x, y, jax.random.PRNGKey(1)))
+    assert seen == {"from_batch_grad": 3, "rank1": 3}
